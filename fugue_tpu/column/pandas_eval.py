@@ -348,7 +348,7 @@ def compile_like_regex(pattern: str) -> "re.Pattern":
     device dictionary LUTs, pandas column algebra) matches with. Anchored
     with ``\A...\Z`` — ``$`` would also match just before a trailing
     newline, so the three evaluators could diverge on values like
-    ``"red\n"`` (ADVICE r5 #3). DOTALL because SQL's ``%``/``_`` match
+    ``"red\n"``. DOTALL because SQL's ``%``/``_`` match
     any character INCLUDING newlines (``'a\nb' LIKE 'a%'`` is TRUE)."""
     return re.compile(
         r"\A" + like_pattern_to_regex(pattern) + r"\Z", re.DOTALL

@@ -445,7 +445,7 @@ def compiled_comap(
         nrows_out = int(out["_nrows"])  # explicit count: one sync
         # an over-reporting cotransformer would make garbage padding rows
         # real; match the host group loop's validation instead of
-        # exporting them (ADVICE r5 #2)
+        # exporting them
         assert_or_throw(
             0 <= nrows_out <= first,
             ValueError(

@@ -65,7 +65,7 @@ def _like_literal(operand: "_Str", pattern: str, negated: bool) -> Masked:
     """LIKE against one literal pattern: a 1D dictionary LUT + gather.
     The LUT rows come from the SAME anchored regex helper the host
     evaluators use, so device and host can never diverge on values like
-    a trailing newline (ADVICE r5 #3)."""
+    a trailing newline."""
     rx = compile_like_regex(pattern)
     d = operand.dictionary
     lut = np.fromiter(

@@ -504,6 +504,20 @@ def _bin_core(
     return seg, first_idx, occupied, occupied.sum().astype(jnp.int32)
 
 
+def float_sort_codes(v: jnp.ndarray) -> List[jnp.ndarray]:
+    """Traced helper: a float key's sort codes. Floats are their OWN sort
+    codes: argsort orders them and the equality-based boundary detection
+    works once the two identity-hostile values are canonicalized — -0.0
+    -> +0.0 (groups with +0.0, host parity) and NaN -> 0.0 with a
+    separate isnan flag code (NaN != NaN would otherwise split every NaN
+    row into its own group). No bitcast of the float: XLA's TPU x64
+    rewriter has refused 64-bit float bitcast-convert operands."""
+    isnan = jnp.isnan(v)
+    v = jnp.where(v == 0, jnp.zeros_like(v), v)
+    v = jnp.where(isnan, jnp.zeros_like(v), v)
+    return [isnan.astype(jnp.int32), v]
+
+
 def _sort_factorize(blocks: JaxBlocks, keys: List[str]) -> Factorized:
     """Lexicographic factorization via repeated stable sorts (general keys:
     floats, wide ints). One host sync for the group count."""
@@ -515,19 +529,7 @@ def _sort_factorize(blocks: JaxBlocks, keys: List[str]) -> Factorized:
         if v.dtype == jnp.bool_:
             v = v.astype(jnp.int32)
         if jnp.issubdtype(v.dtype, jnp.floating):
-            # Floats are their OWN sort codes: argsort orders them and the
-            # equality-based boundary detection below works once the two
-            # identity-hostile values are canonicalized — -0.0 -> +0.0
-            # (groups with +0.0, host parity) and NaN -> 0.0 with a
-            # separate isnan flag code (NaN != NaN would otherwise split
-            # every NaN row into its own group). No bitcast anywhere: any
-            # 64-bit bitcast-convert operand trips XLA's TPU x64 rewriter
-            # (INTERNAL: bitcast-convert not implemented) regardless of
-            # the target word shape (advisor r2, high).
-            isnan = jnp.isnan(v)
-            v = jnp.where(v == 0, jnp.zeros_like(v), v)
-            v = jnp.where(isnan, jnp.zeros_like(v), v)
-            pair = [isnan.astype(jnp.int32), v]
+            pair = float_sort_codes(v)
         elif v.dtype in (jnp.int64, jnp.uint64):
             words = jax.lax.bitcast_convert_type(v, jnp.uint32)
             pair = [words[:, 0].astype(jnp.int32),
@@ -599,6 +601,13 @@ def _sort_factorize_finish(
     return seg, first_idx
 
 
+def avg_dtype(dtype: Any) -> Any:
+    """Result dtype of AVG over a ``dtype`` column: float32 stays float32;
+    integers average in float64, as the host engine does (an int column's
+    mean in float32 keeps only ~7 digits)."""
+    return jnp.float32 if dtype == jnp.float32 else jnp.float64
+
+
 def _segment_agg_impl(
     func: str,
     values: jnp.ndarray,
@@ -637,8 +646,7 @@ def _segment_agg_impl(
         if f == "sum":
             return total, count > 0  # all-null group -> NULL (SQL)
         avg = total / jnp.maximum(count, 1)
-        return avg.astype(jnp.float64 if values.dtype == jnp.float64 else
-                          jnp.float32), count > 0
+        return avg.astype(avg_dtype(values.dtype)), count > 0
     # int32 accumulation: int64 is emulated on TPU; counts fit int32 (<2B
     # rows); callers cast the output to the schema type
     count = jax.ops.segment_sum(

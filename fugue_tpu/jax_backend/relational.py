@@ -425,7 +425,7 @@ def expand_join(
         # ingest): no expansion, no output-cardinality readback — the
         # output is the left frame with right columns gathered in and a
         # validity mask. ZERO host syncs (the general path's one count
-        # sync costs a full relay round trip on network-attached TPUs).
+        # sync stalls dispatch until the device drains).
         return _unique_right_join(
             engine, b1, b2, how, S, seg1, seg2, null1, null2,
             schema1, schema2, out_schema,

@@ -20,7 +20,7 @@ segments-bucket, payload-bucket) shape is seen on a mesh, each candidate
 kernel runs on a small synthetic probe placed on that mesh's first
 device, and the measured winner is cached for the life of the process.
 The choice is empirical per mesh, not guessed — a v5e, a v4 and a CPU
-relay will each converge to their own table. Autotune is off on CPU
+mesh will each converge to their own table. Autotune is off on CPU
 meshes by default (the prior is unambiguous and tier-1 tests run there).
 """
 

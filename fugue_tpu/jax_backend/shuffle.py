@@ -64,12 +64,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from fugue_tpu.jax_backend import groupby
 
@@ -120,7 +116,7 @@ def sharded_cumsum(mesh: Optional[Mesh], x: Any) -> Any:
 
     out = shard_map(
         _body, mesh=mesh, in_specs=P("p"), out_specs=P("p"),
-        check_rep=False,
+        check_vma=False,
     )(xp)
     return out[:n] if pad else out
 
@@ -177,7 +173,7 @@ def sharded_expand_rows(mesh: Mesh, start: Any, out_n: int) -> Any:
 
     body = shard_map(
         _body, mesh=mesh, in_specs=(P("p"),), out_specs=P("p"),
-        check_rep=False,
+        check_vma=False,
     )
     return body(st)
 
@@ -262,7 +258,7 @@ def sharded_grouped_order(
 
     body = shard_map(
         _body, mesh=mesh, in_specs=(P("p"),),
-        out_specs=(P(), P(), P("p")), check_rep=False,
+        out_specs=(P(), P(), P("p")), check_vma=False,
     )
     return body(seg.astype(jnp.int32))
 
@@ -499,7 +495,7 @@ def shuffled_segment_aggs(
         mesh=mesh,
         in_specs=(P("p"), P("p"), P("p"), P("p")),
         out_specs=P("p"),
-        check_rep=False,
+        check_vma=False,
     )
     flat = body(seg.astype(jnp.int32), valid, vals_in, masks_in)
     canon = jnp.asarray(_canon_perm(num_segments, ndev))
@@ -648,10 +644,7 @@ def preagg_segment_aggs(
                 tot = jnp.sum(R[0], axis=0)
                 cnt = jnp.sum(R[1], axis=0)
                 av = tot / jnp.maximum(cnt, 1)
-                dt = vals_[i].dtype
-                v_o = av.astype(
-                    jnp.float64 if dt == jnp.float64 else jnp.float32
-                )
+                v_o = av.astype(groupby.avg_dtype(vals_[i].dtype))
                 m_o = cnt > 0
             elif tag == "min":
                 v_o = jnp.min(R[0], axis=0)
@@ -692,7 +685,7 @@ def preagg_segment_aggs(
         mesh=mesh,
         in_specs=(P("p"), P("p"), P("p"), P("p")),
         out_specs=P("p"),
-        check_rep=False,
+        check_vma=False,
     )
     flat = body(seg.astype(jnp.int32), valid, vals_in, masks_in)
     # reduce-scatter layout is ALREADY canonical: global position
@@ -754,7 +747,7 @@ def shuffle_rows(
         mesh=mesh,
         in_specs=(P("p"), P("p"), P("p")),
         out_specs=P("p"),
-        check_rep=False,
+        check_vma=False,
     )
     out = body(seg.astype(jnp.int32), valid, dict(arrays))
     seg_sh, marker = out[0], out[1]

@@ -99,6 +99,25 @@ def resolve_cache_dir(conf: Any, log: Any = None) -> str:
     return legacy
 
 
+def place_jax_compile_cache(checkout: str) -> str:
+    """Turn on JAX's own persistent compilation cache for an ENTRY-POINT
+    script (``chip_smoke.py``, ``bench.py``) and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing is
+    set here. Unset: ``<checkout>/.jax_cache`` — a FIXED path, because
+    the path is part of what a later process must find again. Never call
+    this at library import or from engine construction: tier-1 runs would
+    fill the checkout with CPU executables."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---- stable key encoding ----------------------------------------------------
 def canonical_key_token(obj: Any) -> Optional[str]:
     """A deterministic, process-stable string for a program key, or None

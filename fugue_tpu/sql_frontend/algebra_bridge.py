@@ -388,7 +388,7 @@ def inline_scalar_subqueries(
             # not lowerable, >1 row, exotic value), the original tree must
             # come out untouched — the host runner reuses it, and a
             # synthetic __scalar__ alias left behind would leak into its
-            # scoping (ADVICE r5 #4)
+            # scoping
             query = node.query
             if (
                 isinstance(node.query, ast.Select)

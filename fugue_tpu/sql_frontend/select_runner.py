@@ -797,7 +797,7 @@ class _Evaluator:
             # the ONE anchored like->regex helper all three evaluators
             # share (device LUTs, pandas_eval, this runner): fullmatch
             # with \A...\Z — str.match + ^...$ would also accept a
-            # trailing newline and silently diverge (ADVICE r5 #3)
+            # trailing newline and silently diverge
             regex = compile_like_regex(str(e.pattern.value))
             matched = s.where(
                 nulls, s.astype(str).str.fullmatch(regex, na=False)

@@ -216,7 +216,7 @@ class ProcessTask(FugueTask):
         # _make_dfs runs only past the checkpoint check, so a
         # deterministic-cache hit never pays input conversion — EXCEPT a
         # raw (non-DataFrame) input under declared input-schema rules,
-        # which has no schema to validate until converted (ADVICE r5 #5)
+        # which has no schema to validate until converted
         processor = _to_processor(self.extension, self.schema)
         self._setup_extension(processor, ctx)
         rules = processor.validation_rules

@@ -105,7 +105,7 @@ def test_group_key_temp_name_no_clobber():
 
 
 def test_like_regex_anchors_and_newlines():
-    # ADVICE r5 #3: one anchored helper for every LIKE evaluator.
+    # One anchored helper for every LIKE evaluator.
     # "red\n" must NOT match 'red' ($ would accept the trailing newline),
     # and %/_ must match newlines (SQL semantics), hence DOTALL.
     from fugue_tpu.column.pandas_eval import compile_like_regex
@@ -118,7 +118,7 @@ def test_like_regex_anchors_and_newlines():
 
 
 def test_like_trailing_newline_host_vs_device():
-    # the exact divergence ADVICE r5 #3 predicted: select_runner's old
+    # the exact divergence predicted: select_runner's old
     # ^...$ + str.match accepted "red\n" LIKE 'red'; device LUTs did not
     import numpy as np
 

@@ -4,10 +4,6 @@ them), the one-hot matmul transient must stay bounded, and empty-input
 aggregates must give identical results whether the emptiness is known on
 the host or pending on device."""
 
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pandas as pd
 import pytest
@@ -98,57 +94,3 @@ def test_empty_aggregate_conventions_identical(keys):
     known_empty = e.to_df(pdf.iloc[:0])
     lazy_empty = e.filter(e.to_df(pdf), col("v") > 100.0)
     assert _agg_rows(e, known_empty, keys) == _agg_rows(e, lazy_empty, keys)
-
-
-_TPU_PROBE = """
-import jax
-devs = jax.devices()
-if all(d.platform == "cpu" for d in devs):
-    raise SystemExit(42)
-import numpy as np, pandas as pd
-from fugue_tpu.column import col
-from fugue_tpu.column import functions as ff
-from fugue_tpu.collections.partition import PartitionSpec
-from fugue_tpu.jax_backend import JaxExecutionEngine
-e = JaxExecutionEngine(dict(test=True))
-pdf = pd.DataFrame({"a": [1.5, -0.0, 0.0, np.nan, np.nan], "b": [1, 2, 4, 8, 16]})
-jdf = e.to_df(pdf)
-assert len(e.distinct(jdf).as_array()) == 5  # all-column distinct
-rows = sorted(e.aggregate(jdf, PartitionSpec(by=["a"]),
-                          [ff.sum(col("b")).alias("s")]).as_array(), key=str)
-assert rows == [[0.0, 6], [1.5, 1], [None, 24]], rows
-print("TPU_OK")
-"""
-
-
-def test_f64_factorize_on_real_accelerator():
-    # the advisor verified the old bitcast path crashed ON TPU only (the
-    # forced-CPU mesh cannot catch it) — run the fixed path on whatever
-    # real accelerator this host has, in a subprocess free of the forced
-    # CPU platform; skip cleanly on CPU-only machines.
-    # Capability gate FIRST, with a short timeout: on some containers the
-    # unforced jax.devices() probe HANGS in the platform plugin for the
-    # full 300s budget — that's the container, not the kernel under test
-    from fugue_tpu.testing.capabilities import has_real_accelerator
-
-    ok, reason = has_real_accelerator()
-    if not ok:
-        pytest.skip(reason)
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "").replace(
-        "--xla_force_host_platform_device_count=8", ""
-    )
-    res = subprocess.run(
-        [sys.executable, "-c", _TPU_PROBE],
-        capture_output=True,
-        text=True,
-        timeout=300,
-        env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-    )
-    if res.returncode == 42:
-        pytest.skip("no accelerator on this host")
-    assert res.returncode == 0, res.stderr[-2000:]
-    assert "TPU_OK" in res.stdout

@@ -282,7 +282,7 @@ def test_untraceable_cotransformer_falls_back_to_host_loop():
 
 
 def test_over_reporting_nrows_is_rejected():
-    # ADVICE r5 #2: a cotransformer claiming more rows than its output
+    # A cotransformer claiming more rows than its output
     # columns hold would turn garbage padding rows into real rows — the
     # compiled path must validate like the host group loop does
     def cm_over(

@@ -265,7 +265,7 @@ def test_program_cost_analysis_reports_traffic():
 
 def test_persist_forces_masks_and_row_valid():
     """persist()'s residency fetch covers column masks and row_valid too
-    (ADVICE r5 #1) — and the persisted frame stays oracle-identical."""
+    — and the persisted frame stays oracle-identical."""
     from fugue_tpu.jax_backend.blocks import residency_arrays
 
     pdf = _frame(500)

@@ -107,7 +107,7 @@ def test_complex_query_falls_back_correctly():
 
 
 def test_inline_scalar_subquery_decline_leaves_ast_untouched():
-    # ADVICE r5 #4: when the inline pass declines (here: run_plan raises),
+    # When the inline pass declines (here: run_plan raises),
     # the parsed tree must come out EXACTLY as parsed — no synthetic
     # __scalar__ alias left behind for the host runner to trip on
     import copy

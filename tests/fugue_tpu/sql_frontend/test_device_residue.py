@@ -1,4 +1,4 @@
-"""Round-5 device-residency closure (VERDICT r4 item 2): NOT IN
+"""Round-5 device-residency closure: NOT IN
 subqueries, uncorrelated scalar subqueries, dynamic (column-valued) LIKE
 patterns, and multi-string-column CONCAT all execute in-engine on device
 with ``fallbacks == {}`` — the reference bar is all-SQL-in-engine

@@ -19,7 +19,7 @@ def _frame(rng: np.random.Generator, n: int = 160) -> pd.DataFrame:
     v = np.round(rng.random(n) * 10, 3)
     v[rng.random(n) < 0.12] = np.nan
     # trailing-newline values exercise the LIKE anchor unification
-    # (ADVICE r5 #3: ^...$ + str.match would accept "red\n" LIKE 'red')
+    # (^...$ + str.match would accept "red\n" LIKE 'red')
     s = rng.choice(
         ["red", "green", "blue", "teal ", "red\n"], n
     ).astype(object)
@@ -280,7 +280,7 @@ def test_fuzz_scalar_subqueries():
 
 
 def test_zz_device_routed_fraction():
-    """The corpus-wide report VERDICT r4 asked for: the differential
+    """The corpus-wide report: the differential
     fuzzer must KNOW how much of its corpus ran device-resident, not
     just per-test thresholds. Skips when the corpus didn't run in this
     process (-k selection, xdist sharding)."""

@@ -1,6 +1,6 @@
 """ProcessTask on a deterministic-checkpoint hit: validations still fire
 (they are workflow declarations), but engine input conversion is skipped —
-a cache hit must not pay ``to_df`` on every input (ADVICE r5 #5)."""
+a cache hit must not pay ``to_df`` on every input."""
 
 import pandas as pd
 import pytest

@@ -1,7 +1,6 @@
 """RPC handler determinism hook: handlers hash into workflow task uuids,
 so a deterministic checkpoint is REUSED across identical builds with the
-same callback and INVALIDATED when the callback changes (VERDICT
-Missing #4)."""
+same callback and INVALIDATED when the callback changes."""
 
 from typing import Callable, List
 
