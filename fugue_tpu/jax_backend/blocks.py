@@ -43,6 +43,7 @@ import pandas as pd
 import pyarrow as pa
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from fugue_tpu.obs.trace import start_span
 from fugue_tpu.schema import Schema
 from fugue_tpu.testing.retrace import active_retrace_sentinel
 from fugue_tpu.utils.assertion import assert_or_throw
@@ -492,7 +493,15 @@ def to_arrow(blocks: JaxBlocks, schema: Schema) -> pa.Table:
     This is THE host boundary: masked-layout frames are compacted here with
     one readback of the validity mask; all lazy row counts materialize.
     All device columns transfer in ONE async wave (per-array readbacks
-    would each pay a full device sync)."""
+    would each pay a full device sync). Spanned as ``engine.fetch``."""
+    with start_span("engine.fetch") as sp:
+        table = _to_arrow(blocks, schema)
+        if sp:
+            sp.set_attr(rows=table.num_rows)
+    return table
+
+
+def _to_arrow(blocks: JaxBlocks, schema: Schema) -> pa.Table:
     for col in blocks.columns.values():
         if col.on_device:
             col.data.copy_to_host_async()

@@ -407,15 +407,19 @@ class JaxSQLEngine(PandasSQLEngine):
         sql = statement.construct(dialect=self.dialect)
         plan = None
         try:
-            schemas = {name: list(df.schema.names) for name, df in dfs.items()}
-            q = parse_select(sql)
-            # uncorrelated scalar subqueries run as device plans NOW and
-            # inline as literals (one scalar readback each); whatever
-            # stays un-inlined makes the outer translate give up below
-            inline_scalar_subqueries(
-                q, schemas, lambda p: self._exec_plan(p, dfs, {})
-            )
-            plan = translate_query(q, schemas)
+            with start_span("sql.plan"):
+                schemas = {
+                    name: list(df.schema.names) for name, df in dfs.items()
+                }
+                q = parse_select(sql)
+                # uncorrelated scalar subqueries run as device plans NOW
+                # and inline as literals (one scalar readback each);
+                # whatever stays un-inlined makes the outer translate
+                # give up below
+                inline_scalar_subqueries(
+                    q, schemas, lambda p: self._exec_plan(p, dfs, {})
+                )
+                plan = translate_query(q, schemas)
         except Exception:
             plan = None
         if plan is not None:
@@ -2435,6 +2439,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 with start_span("engine.dispatch", program=name) as sp:
                     out = compiled(*args)
                     if sp:
+                        sp.set_attr(compiled=0)
                         sp.name = "engine.execute"
                 # an AOT dispatch is compile-free by construction: it
                 # counts as a hit on the per-dispatch compile surface
@@ -2617,8 +2622,10 @@ class JaxExecutionEngine(ExecutionEngine):
             else:
                 self._compile_hits.inc()
             if sp:
-                # spans are plain records: the name settles once the
-                # dispatch revealed whether it compiled
+                # the obs name settles once the dispatch revealed whether
+                # it compiled; the profiler event, named at its start,
+                # stays engine.dispatch and carries the attribute
+                sp.set_attr(compiled=int(compiled))
                 sp.name = "engine.compile" if compiled else "engine.execute"
         self._add_dispatch_secs(
             "compile" if compiled else "execute", time.perf_counter() - t0

@@ -3,12 +3,13 @@
 
 The contract that keeps instrumentation free when observability is off:
 
-- A span is only ever recorded under an ACTIVE trace. The thread-local
-  context holds the current :class:`Span`; :func:`start_span` with no
-  current span returns the shared :data:`NULL_CM` singleton — one
-  function call, one thread-local read, **zero allocation** — so the
-  hundreds of instrumentation sites across the engine cost nothing on
-  workloads that never opened a trace.
+- A span is only ever recorded under an ACTIVE trace (or, below, by
+  the profiler). The thread-local context holds the current
+  :class:`Span`; :func:`start_span` with no current span and no
+  profiler recording returns the shared :data:`NULL_CM` singleton —
+  one function call, one thread-local read, one profiler check, **zero
+  allocation** — so the hundreds of instrumentation sites across the
+  engine cost nothing on workloads that never opened a trace.
 - Traces are OPENED only at the two entry points that own a request's
   lifecycle: the serving daemon (per HTTP request, trace id =
   ``X-Request-Id``) and ``FugueWorkflow.run`` (embedded use, when no
@@ -22,6 +23,13 @@ The contract that keeps instrumentation free when observability is off:
 Spans carry ``time.time_ns`` wall-clock bounds (exported as Chrome
 trace-event microseconds) plus the executing thread id, so a Perfetto
 load shows queue/compile/execute/transfer lanes per thread.
+
+While ``jax.profiler`` records, every :func:`start_span` span is ALSO a
+profiler event (a ``TraceAnnotation`` named like the span, its
+attributes as event metadata), on the profiler's clock beside the
+device's ops, whether or not an obs trace is open: with neither, a site
+costs one more call, ``TraceMe.is_enabled()``. :func:`begin_span` spans,
+whose ends may land on another thread, stay obs-only.
 """
 
 import itertools
@@ -30,9 +38,13 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from fugue_tpu.testing.locktrace import tracked_lock
 
 _TLS = threading.local()
+# True while a jax.profiler session records on this process
+_profiler_recording = TraceAnnotation.is_enabled
 
 
 def current_span() -> Optional["Span"]:
@@ -50,6 +62,7 @@ class Span:
         "end_ns",
         "thread_id",
         "attrs",
+        "_event",
     )
 
     def __init__(
@@ -68,9 +81,13 @@ class Span:
         self.end_ns: Optional[int] = None
         self.thread_id = threading.get_ident()
         self.attrs: Dict[str, Any] = attrs or {}
+        # the open profiler event of a start_span span (None otherwise)
+        self._event: Optional[TraceAnnotation] = None
 
     def set_attr(self, **kv: Any) -> None:
         self.attrs.update(kv)
+        if self._event is not None:
+            self._event.set_metadata(**kv)
 
     def finish(self) -> None:
         """Idempotent end; the trace's open-span count drops on the
@@ -92,9 +109,9 @@ class Span:
 
 
 class _NullSpan:
-    """The span-shaped no-op sites receive when tracing is off: every
+    """The span-shaped no-op sites receive when nothing records: every
     method swallows its arguments; truthiness is False so guards can
-    branch on a real span cheaply."""
+    skip work that only a recorded span would keep."""
 
     __slots__ = ()
 
@@ -124,6 +141,37 @@ class _NullCM:
 
 
 NULL_CM = _NullCM()
+
+
+class _ProfilerSpan:
+    """What :func:`start_span` gives when no obs trace is open but the
+    profiler records: a profiler event, its own context manager and
+    span. ``set_attr`` reaches the event's metadata, and it is truthy
+    like an obs span, so ``if sp:`` guards record attributes on it. The
+    event's name is fixed when it starts: a site's later ``name`` change
+    reaches only an obs span."""
+
+    __slots__ = ("_event", "name")
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name = name
+        self._event = TraceAnnotation(name, **attrs)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._event.__enter__()
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
+        if exc_type is not None:
+            self._event.set_metadata(error=exc_type.__name__)
+        self._event.__exit__(None, None, None)
+        return False
+
+    def set_attr(self, **kv: Any) -> None:
+        self._event.set_metadata(**kv)
+
+    def finish(self) -> None:
+        pass
 
 
 class Trace:
@@ -219,17 +267,27 @@ class _SpanCM:
         self._span: Optional[Span] = None
 
     def __enter__(self) -> Span:
-        self._span = self._parent.trace.start_span(
+        span = self._parent.trace.start_span(
             self._name, self._parent, self._attrs or None
         )
-        _TLS.span = self._span
-        return self._span
+        if _profiler_recording():
+            span._event = TraceAnnotation(self._name, **self._attrs)
+            span._event.__enter__()
+        self._span = span
+        _TLS.span = span
+        return span
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         span = self._span
         if span is not None:
             if exc_type is not None:
                 span.attrs.setdefault("error", exc_type.__name__)
+            event = span._event
+            if event is not None:
+                span._event = None
+                if exc_type is not None:
+                    event.set_metadata(error=exc_type.__name__)
+                event.__exit__(None, None, None)
             span.finish()
         _TLS.span = self._parent
         return False
@@ -256,10 +314,14 @@ class _ActivateCM:
 
 
 def start_span(name: str, **attrs: Any) -> Any:
-    """Context manager for one child span of the thread's current span.
-    No active trace → the shared no-op singleton (nothing allocated)."""
+    """Context manager for one child span of the thread's current span,
+    also a profiler event while the profiler records. No active trace:
+    a profiler event alone, or, with no profiler recording, the shared
+    no-op singleton (nothing allocated)."""
     cur = getattr(_TLS, "span", None)
     if cur is None:
+        if _profiler_recording():
+            return _ProfilerSpan(name, attrs)
         return NULL_CM
     return _SpanCM(cur, name, attrs)
 

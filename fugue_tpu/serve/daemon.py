@@ -1398,10 +1398,16 @@ class ServeDaemon:
         # the workflow layer does not re-draw and export an
         # uncorrelated trace of its own.
         if self._obs.enabled and job.obs_span is None:
-            with suppress_tracing():
-                return self._timed_execute(job)
-        with activate(job.obs_span):
-            with start_span("serve.execute"):
+            scope = suppress_tracing()
+        else:
+            scope = activate(job.obs_span)
+        # queued_us: the scheduler wait (try_start stamped started_at at
+        # pickup), carried by the span that ends on this thread
+        queued_us = round((job.started_at - job.submitted_at) * 1e6)
+        with scope:
+            with start_span(
+                "serve.execute", job_id=job.job_id, queued_us=queued_us
+            ):
                 return self._timed_execute(job)
 
     def _timed_execute(self, job: ServeJob) -> Dict[str, Any]:
@@ -1603,15 +1609,16 @@ class ServeDaemon:
                 with engine_dispatch_guard(self._engine, job.token):
                     local = df.head(job.limit + 1)
                 job.beat()
-                rows = local.as_array(type_safe=True)
-                truncated = len(rows) > job.limit
-                payload["result"] = {
-                    "columns": list(df.schema.names),
-                    "types": str(df.schema),
-                    "rows": rows[: job.limit],
-                    "row_count": min(len(rows), job.limit),
-                    "truncated": truncated,
-                }
+                with start_span("serve.encode"):
+                    rows = local.as_array(type_safe=True)
+                    truncated = len(rows) > job.limit
+                    payload["result"] = {
+                        "columns": list(df.schema.names),
+                        "types": str(df.schema),
+                        "rows": rows[: job.limit],
+                        "row_count": min(len(rows), job.limit),
+                        "truncated": truncated,
+                    }
         if cache_key is not None:
             result = payload.get("result") or {}
             nbytes = 64 + 16 * len(result.get("rows") or []) * max(

@@ -14,6 +14,7 @@ traceback.
 import json
 from typing import TYPE_CHECKING, Any
 
+from fugue_tpu.obs.trace import NULL_SPAN, start_span
 from fugue_tpu.rpc.http import (
     HardenedRequestHandler,
     HTTPRPCServer,
@@ -65,15 +66,17 @@ class _ServeAPIHandler(HardenedRequestHandler):
         # correlation id FIRST: even a 400/413 body rejection (written
         # inside read_body, before routing) must echo X-Request-Id
         self._begin_request()
-        body = self.read_body()  # 413 already sent when over the cap
-        if body is None:
-            return
-        self._route("POST", body)
+        # the whole request on this thread: body, routing, encode, write
+        with start_span("http.request") as sp:
+            body = self.read_body()  # 413 already sent when over the cap
+            if body is None:
+                return
+            self._route("POST", body, sp)
 
     def _begin_request(self) -> None:
         self._request_id = self.headers.get("X-Request-Id")
 
-    def _route(self, method: str, body: bytes) -> None:
+    def _route(self, method: str, body: bytes, span: Any = NULL_SPAN) -> None:
         try:
             payload = json.loads(body.decode("utf-8")) if body else {}
             if not isinstance(payload, dict):
@@ -98,6 +101,14 @@ class _ServeAPIHandler(HardenedRequestHandler):
         status, resp, headers = daemon.handle_api(
             method, self.path, payload, request_id=self._request_id
         )
+        if span:
+            # job_id pairs this request with its job's serve.execute
+            job_id = resp.get("job_id") if isinstance(resp, dict) else None
+            span.set_attr(
+                route=daemon._route_family(self.path),
+                status=status,
+                **({"job_id": job_id} if job_id else {}),
+            )
         self._send_json(status, resp, headers)
 
     def _send_bytes(
@@ -130,7 +141,11 @@ class _ServeAPIHandler(HardenedRequestHandler):
     def _send_json(
         self, status: int, resp: Any, headers: Any = None
     ) -> None:
-        self._send_bytes(status, dumps(resp), "application/json", headers)
+        with start_span("serve.encode") as sp:
+            data = dumps(resp)
+            if sp:
+                sp.set_attr(bytes=len(data))
+        self._send_bytes(status, data, "application/json", headers)
 
     def send_error_payload(self, status: int, ex: BaseException) -> None:
         self._send_json(status, {"error": structured_error(ex)})

@@ -7,6 +7,7 @@ from typing import Any, Dict, Optional, Tuple
 from fugue_tpu.constants import FUGUE_CONF_SQL_DIALECT
 from fugue_tpu.dataframe import DataFrame
 from fugue_tpu.execution.factory import make_execution_engine
+from fugue_tpu.obs.trace import start_span
 from fugue_tpu.sql_frontend.fugue_parser import FugueSQLCompiler
 from fugue_tpu.workflow.workflow import FugueWorkflow, WorkflowDataFrame
 
@@ -86,15 +87,16 @@ class FugueSQLWorkflow(FugueWorkflow):
         local_vars = dict(caller_vars or {})
         local_vars.update(params)
         code = fill_sql_template(code, params)
-        compiler = FugueSQLCompiler(
-            workflow=self,
-            variables=self._sql_vars,
-            sources=sources,
-            local_vars=local_vars,
-            dialect=self._conf.get(FUGUE_CONF_SQL_DIALECT, "spark"),
-            last=self.last_df,
-        )
-        variables = compiler.compile(code)
+        with start_span("sql.compile"):
+            compiler = FugueSQLCompiler(
+                workflow=self,
+                variables=self._sql_vars,
+                sources=sources,
+                local_vars=local_vars,
+                dialect=self._conf.get(FUGUE_CONF_SQL_DIALECT, "spark"),
+                last=self.last_df,
+            )
+            variables = compiler.compile(code)
         for k, v in variables.items():
             if isinstance(v, WorkflowDataFrame) and v.workflow is self:
                 self._sql_vars[k] = v

@@ -4,7 +4,6 @@ fugue/workflow/workflow.py:88-2302 re-built on our own runner/tasks).
 ``FugueWorkflow()`` collects operations as deterministic tasks;
 ``run(engine)`` executes them (nothing is compiled before that)."""
 
-from contextlib import nullcontext
 from typing import Any, Callable, Dict, Iterable, List, Optional, Union
 from uuid import uuid4
 
@@ -893,22 +892,27 @@ class FugueWorkflow:
         # OWNS a per-run trace — exported to fugue.obs.trace_path and
         # slow-query-checked at the end
         opts = obs_options(e.conf)
-        owned_trace = None
-        if not opts.enabled or tracing_suppressed():
-            # suppressed: a serving daemon's job whose request lost the
-            # sampling draw — re-drawing here would export uncorrelated
-            # traces at ~double the configured rate
-            run_scope: Any = nullcontext()
-        elif current_span() is not None:
-            run_scope = start_span("workflow.run", tasks=len(self._tasks))
-        else:
+        owned_trace = obs_root = None
+        # never under suppression: a serving daemon's job whose request
+        # lost the sampling draw — re-drawing here would export
+        # uncorrelated traces at ~double the configured rate
+        if (
+            opts.enabled
+            and not tracing_suppressed()
+            and current_span() is None
+        ):
             owned_trace, obs_root = open_trace(
                 opts,
                 "workflow.run",
                 workflow=self.__uuid__()[:12],
                 tasks=len(self._tasks),
             )
-            run_scope = activate(obs_root)
+        if obs_root is not None:
+            run_scope: Any = activate(obs_root)
+        else:
+            # a child of the ambient trace, else a profiler event alone
+            # (or the shared no-op)
+            run_scope = start_span("workflow.run", tasks=len(self._tasks))
         try:
             with run_scope:
                 return self._run_inner(e, conf, cancel_token)

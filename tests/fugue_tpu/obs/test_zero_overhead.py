@@ -90,3 +90,23 @@ def test_obs_off_jax_run_keeps_back_compat_counters_only():
     assert isinstance(e.compile_cache_stats["hits"], int)
     # ...but nothing trace-shaped exists
     assert e.metrics.get("fugue_obs_traces_exported_total") is None
+
+
+def test_profiler_off_and_no_trace_is_still_the_singleton(tmp_path):
+    # the profiler bridge costs the off path one is_enabled() call: with
+    # no profiler recording and no trace, every site is the singleton
+    # again, before and after a profiler session
+    import jax
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert start_span("engine.fetch", rows=1) is NULL_CM
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with start_span("engine.fetch") as sp:
+            assert sp is not NULL_SPAN and current_span() is None
+        assert begin_span("engine.transfer") is NULL_SPAN  # obs-only
+    finally:
+        jax.profiler.stop_trace()
+    assert start_span("engine.fetch", rows=1) is NULL_CM
+    with start_span("x") as sp:
+        assert sp is NULL_SPAN
