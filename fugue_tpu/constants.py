@@ -312,16 +312,18 @@ def _declare_defaults() -> None:
     # defers to fugue.jax.groupby.strategy.
     r(FUGUE_CONF_JAX_GROUPBY_MATMUL, str, "auto", "legacy matmul pin: auto | always | never")
     # segment-reduction strategy: "auto" consults the measured crossover
-    # table in jax_backend/segtune.py (scatter on CPU meshes, one-hot
-    # matmul on accelerators below the segment cap, sorted scatter above
-    # it), sharpened by a one-shot on-device autotune; or pin one of
-    # "matmul" | "matmul_bf16" | "scatter" | "sort". matmul_bf16 trades
+    # table in jax_backend/segtune.py (scatter on CPU meshes; on
+    # accelerators masked VPU sums for f64 payloads over few bins, one-hot
+    # matmul below the segment cap, sorted scatter above it), sharpened
+    # by a one-shot on-device autotune; or pin one of "matmul" |
+    # "matmul_bf16" | "scatter" | "sort" | "masked". matmul_bf16 trades
     # ~8 mantissa bits for speed and is PIN-ONLY — auto never picks it.
     r(
         FUGUE_CONF_JAX_GROUPBY_STRATEGY,
         str,
         "auto",
-        "segment-reduction kernel: auto | matmul | matmul_bf16 | scatter | sort",
+        "segment-reduction kernel: "
+        "auto | matmul | matmul_bf16 | scatter | sort | masked",
     )
     # autotune policy: "auto" probes on accelerator meshes for large
     # frames only; True/False force it on/off. Mixed-type by design.

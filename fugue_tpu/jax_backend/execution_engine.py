@@ -2824,7 +2824,7 @@ class JaxExecutionEngine(ExecutionEngine):
         bspec = groupby.bin_spec(blocks, keys)
         if bspec is not None:
             kinds = []
-            need_int, all_f32 = False, True
+            need_int, float_dtype = False, np.float32
             for _, func, arg, _ in typed_plans:
                 kind = self._packed_agg_kind(jdf, func, arg)
                 kinds.append(kind)
@@ -2833,7 +2833,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 elif kind == "float":
                     atp = arg.infer_type(jdf.schema)
                     if atp is None or not pa.types.is_float32(atp):
-                        all_f32 = False
+                        float_dtype = np.float64
             if all(k is not None for k in kinds):
                 # payload estimate for the crossover table: every plan
                 # contributes at most one payload row + the occupancy slot
@@ -2844,7 +2844,7 @@ class JaxExecutionEngine(ExecutionEngine):
                     bspec.total,
                     1 + len(typed_plans),
                     need_int=need_int,
-                    all_f32=all_f32,
+                    float_dtype=float_dtype,
                 )
                 if strategy is not None:
                     return self._binned_packed_aggregate(
@@ -3207,24 +3207,31 @@ class JaxExecutionEngine(ExecutionEngine):
         num_segments: int,
         n_payload: int,
         need_int: bool = False,
-        all_f32: bool = True,
+        float_dtype: Any = np.float32,
     ) -> Optional[str]:
         """Select the packed segment-reduction strategy for one aggregate
         shape, or None when no strategy is eligible (the caller then takes
-        the generic per-agg path). Eligibility: the matmul family cannot
-        sum integers exactly and is capped at _MATMUL_MAX_SEGMENTS (the
-        one-hot transient), matmul_bf16 additionally needs all-f32 float
-        payloads; scatter/sort run up to _PACKED_MAX_SEGMENTS. ``auto``
-        consults segtune's measured table + one-shot on-device autotune;
-        an explicit conf pin is honored when eligible."""
+        the generic per-agg path). ``float_dtype`` is the float payloads'
+        accumulator dtype (f32 when every float payload is f32 or there is
+        none, else f64). Eligibility: the matmul family and masked cannot
+        sum integers exactly; matmul is capped at _MATMUL_MAX_SEGMENTS
+        (the one-hot transient), matmul_bf16 additionally needs f32 float
+        payloads, masked runs up to _MASKED_MAX_SEGMENTS bins;
+        scatter/sort run up to _PACKED_MAX_SEGMENTS. ``auto`` consults
+        segtune's measured table + one-shot on-device autotune and offers
+        masked only for f64 payloads; an explicit conf pin is honored
+        when eligible."""
         from fugue_tpu.constants import FUGUE_CONF_JAX_GROUPBY_AUTOTUNE
         from fugue_tpu.jax_backend import segtune
 
+        f64 = np.dtype(float_dtype) == np.float64
         candidates: List[str] = []
         if not need_int and num_segments <= groupby._MATMUL_MAX_SEGMENTS:
             candidates.append("matmul")
-            if all_f32:
+            if not f64:
                 candidates.append("matmul_bf16")
+        if not need_int and num_segments <= groupby._MASKED_MAX_SEGMENTS:
+            candidates.append("masked")
         if num_segments <= groupby._PACKED_MAX_SEGMENTS:
             candidates.extend(["scatter", "sort"])
         if not candidates:
@@ -3234,8 +3241,13 @@ class JaxExecutionEngine(ExecutionEngine):
             return mode if mode in candidates else None
         # bf16's hi/lo split trades ~8 mantissa bits for speed — an
         # accuracy change users must PIN into, never an autotune pick
-        # (review finding)
-        candidates = [c for c in candidates if c != "matmul_bf16"]
+        # (review finding). masked is auto's kernel for f64 only: f32
+        # keeps the MXU matmul its measured table picks
+        candidates = [
+            c
+            for c in candidates
+            if c != "matmul_bf16" and (f64 or c != "masked")
+        ]
         return segtune.choose_strategy(
             blocks.mesh,
             rows,
@@ -3244,6 +3256,7 @@ class JaxExecutionEngine(ExecutionEngine):
             candidates,
             typed_conf_get(self.conf, FUGUE_CONF_JAX_GROUPBY_AUTOTUNE),
             self.log,
+            float_dtype=float_dtype,
         )
 
     def _count_reduce_strategy(

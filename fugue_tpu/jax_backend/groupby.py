@@ -167,10 +167,10 @@ def decode_bin_keys(
 # All sum-type reductions (sum/avg/count payloads for every aggregated
 # column) are packed into one multi-row operand so the per-row segment
 # work — one-hot materialization, scatter index handling, or the sort —
-# is amortized across every output. Four interchangeable strategies
+# is amortized across every output. Five interchangeable strategies
 # compute the identical contract; the engine picks one per (rows,
-# num_segments, n_payload, placement tier) via a measured table + a
-# one-shot on-device autotune (see segtune.py):
+# num_segments, n_payload, float dtype, placement tier) via a measured
+# table + a one-shot on-device autotune (see segtune.py):
 #
 # - "matmul": chunked one-hot matmul over the MXU. The fastest measured
 #   on TPU for small segment counts. Benchmarked at 100M rows x 1024
@@ -184,7 +184,8 @@ def decode_bin_keys(
 #   Chunk-size sweeps (2^16..2^20) move the time <15%, so the cost is
 #   the inherent n*num_segments one-hot work, not scan-step overhead — a
 #   pallas kernel was evaluated and offers no algorithmic advantage here
-#   (VPU compare-accumulate is the same n*S work at lower throughput).
+#   (VPU compare-accumulate is the same n*S work at lower throughput in
+#   f32; in f64 it is the other way round, see "masked").
 # - "matmul_bf16": the same one-hot matmul with the one-hot in bf16 and
 #   each f32 payload split into hi+lo bf16 halves (two exact-0/1-weighted
 #   products, f32 MXU accumulation) — halves the one-hot transient
@@ -201,11 +202,26 @@ def decode_bin_keys(
 #   cheaper kernel, trading the n*S one-hot work for an n*log(n) sort.
 #   The crossover candidate for LARGE segment counts where the one-hot
 #   work dominates.
+# - "masked": one masked select-and-sum per (segment, payload) on the
+#   VPU, each payload's bins fused into one pass over its column — no
+#   one-hot reaches HBM and no matmul runs. The f64 kernel: the MXU has no float64, so XLA
+#   emulates an f64 one-hot dot_general, and the (rows, S) one-hot pads
+#   S out to a full lane tile. Measured on one v5e at 10M rows x 1,024
+#   segments, f64 (PR 21): matmul 5.265s, scatter 0.956s, sort 1.398s
+#   (matmul in f32: 0.0117s). On one v5e, TPC-H Q1 at SF10 (60M rows,
+#   12 bins, 6 payloads, f64, PR 24): the whole Q1 program 58ms against
+#   3.15s on the f64 matmul; the autotune probe at 1M rows x 12 bins x 9
+#   payloads, f64: masked 2.4-5.7ms, matmul 67-70ms, scatter 106-110ms,
+#   sort 264-268ms. Its work is rows * S * P, so it is capped at
+#   _MASKED_MAX_SEGMENTS; f64 shapes past the cap are unmeasured.
 # ---------------------------------------------------------------------------
 
-STRATEGIES = ("matmul", "matmul_bf16", "scatter", "sort")
+STRATEGIES = ("matmul", "matmul_bf16", "scatter", "sort", "masked")
 
 _MATMUL_MAX_SEGMENTS = 8192
+# masked work grows as rows * num_segments * n_payload: past a few dozen
+# bins one pass per bin loses to a single scatter or sort
+_MASKED_MAX_SEGMENTS = 64
 # scatter/sort have no one-hot transient: the packed path stays viable up
 # to the bin cap itself (output is (num_segments, n_payload))
 _PACKED_MAX_SEGMENTS = 1 << 20
@@ -237,6 +253,15 @@ def segment_sums(
         strategy in STRATEGIES,
         ValueError(f"unknown segment-reduction strategy {strategy!r}"),
     )
+    if strategy == "masked":
+        assert_or_throw(
+            len(ints) == 0,
+            ValueError("the masked strategy cannot sum integer payloads"),
+        )
+        f, c = masked_segment_sums(
+            float_payloads, count_payloads, seg, num_segments
+        )
+        return f, c, []
     if strategy in ("matmul", "matmul_bf16"):
         assert_or_throw(
             len(ints) == 0,
@@ -326,6 +351,41 @@ def _packed_scatter_sums(
         _reduce(float_payloads, acc_dtype),
         _reduce(count_payloads, jnp.int32),
         _reduce(int_payloads, jnp.int64),
+    )
+
+
+def masked_segment_sums(
+    float_payloads: List[jnp.ndarray],
+    count_payloads: List[jnp.ndarray],
+    seg: jnp.ndarray,
+    num_segments: int,
+) -> Tuple[List[jnp.ndarray], List[jnp.ndarray]]:
+    """Traced helper: every sum-type reduction as masked sums on the VPU.
+    Each payload reduces ``where(seg == s, p, 0)`` over the rows for every
+    bin ``s``, as one (num_segments, rows) select-and-sum that XLA fuses
+    into its reduction: the bin mask is recomputed in registers, so a
+    payload's column is read once for all bins and no one-hot reaches
+    HBM. Rows run along the lanes and bins along the sublanes (on the
+    TPU, XLA first splits an f64 column into two f32 halves).
+    ``float_payloads`` accumulate in the widest float dtype present (f64
+    stays f64); ``count_payloads`` (bool/0-1 valued) accumulate exactly
+    in int32. ``seg`` values >= num_segments match no bin and contribute
+    nothing."""
+    acc_dtype = _float_acc_dtype(float_payloads)
+    eq = seg[None, :] == jnp.arange(num_segments, dtype=seg.dtype)[:, None]
+
+    def _sum(p: jnp.ndarray, dtype: Any) -> jnp.ndarray:
+        # dtype= keeps int32 counts int32 (jnp.sum would widen to int64,
+        # which the TPU emulates as a pair of 32-bit words)
+        return jnp.sum(
+            jnp.where(eq, p.astype(dtype)[None, :], jnp.zeros((), dtype)),
+            axis=1,
+            dtype=dtype,
+        )
+
+    return (
+        [_sum(p, acc_dtype) for p in float_payloads],
+        [_sum(p, jnp.int32) for p in count_payloads],
     )
 
 

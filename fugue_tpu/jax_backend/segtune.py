@@ -8,17 +8,24 @@ the shape — measured crossovers (r3/r6):
 - CPU meshes (the host placement tier): packed scatter-add, always. The
   (chunk, segments) one-hot transient is pure memory-bandwidth waste on
   CPU (10M rows x 256 segments: 1.28s matmul vs 0.048s scatter).
-- Accelerator meshes, small segment counts: one-hot matmul on the MXU
-  (scatter serializes there; measured 50x worse at 1024 segments).
+- Accelerator meshes, small segment counts, f32 payloads: one-hot
+  matmul on the MXU (scatter serializes there; measured 50x worse at
+  1024 segments).
+- Accelerator meshes, few segments, f64 payloads: masked sums on the VPU.
+  The MXU has no float64, and the emulated f64 one-hot matmul is the
+  slowest kernel there (PR 21: 5.265s vs 0.0117s in f32 at 10M rows x
+  1,024 segments).
 - Accelerator meshes, large segment counts: the n*num_segments one-hot
   work dominates; sorting by segment id and scattering with
   ``indices_are_sorted=True`` crosses over.
 
 ``choose_strategy`` encodes that table as the prior and sharpens it with
 a ONE-SHOT on-device autotune: the first time a (platform, rows-bucket,
-segments-bucket, payload-bucket) shape is seen on a mesh, each candidate
-kernel runs on a small synthetic probe placed on that mesh's first
-device, and the measured winner is cached for the life of the process.
+segments-bucket, payload-bucket, float dtype) shape is seen on a mesh,
+each candidate kernel runs on a small synthetic probe, with float
+payloads in the query's own accumulator dtype, placed on that mesh's
+first device, and the measured winner is cached for the life of the
+process.
 The choice is empirical per mesh, not guessed — a v5e, a v4 and a CPU
 mesh will each converge to their own table. Autotune is off on CPU
 meshes by default (the prior is unambiguous and tier-1 tests run there).
@@ -32,14 +39,16 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from fugue_tpu.jax_backend.groupby import (
+    _MASKED_MAX_SEGMENTS,
     _MATMUL_MAX_SEGMENTS,
     STRATEGIES,
     segment_sums,
 )
 
-# (platform, rows_bucket, segments_bucket, payload_bucket, candidates)
-# -> measured winner. Process-lifetime cache: autotune is one-shot per
-# mesh shape class, mirroring the persistent XLA compile cache's role.
+# (platform, rows_bucket, segments_bucket, payload_bucket, float dtype,
+# candidates) -> measured winner. Process-lifetime cache: autotune is
+# one-shot per mesh shape class, mirroring the persistent XLA compile
+# cache's role.
 _TUNE_CACHE: Dict[Tuple, str] = {}
 # observability: how many probe sweeps actually ran (tests pin one-shot)
 _TUNE_RUNS = {"count": 0}
@@ -63,12 +72,19 @@ def _bucket(x: int) -> int:
 
 
 def heuristic_strategy(
-    platform: str, num_segments: int, n_payload: int
+    platform: str, num_segments: int, n_payload: int,
+    float_dtype: Any = np.float32,
 ) -> str:
     """The measured-table prior (used directly when autotune is off or the
-    shape is too small to be worth probing)."""
+    shape is too small to be worth probing). ``float_dtype`` is the float
+    payloads' accumulator dtype."""
     if platform == "cpu":
         return "scatter"
+    if (
+        np.dtype(float_dtype) == np.float64
+        and num_segments <= _MASKED_MAX_SEGMENTS
+    ):
+        return "masked"
     if num_segments <= _MATMUL_MAX_SEGMENTS:
         return "matmul"
     return "sort"
@@ -166,14 +182,20 @@ def choose_strategy(
     candidates: Sequence[str],
     autotune_conf: Any = "auto",
     log: Optional[Any] = None,
+    float_dtype: Any = np.float32,
 ) -> str:
     """Pick the segment-reduction strategy for one reduction shape.
 
     ``candidates`` is the caller-filtered eligible subset of STRATEGIES
-    (e.g. matmul family removed when exact integer sums are present)."""
+    (e.g. matmul family removed when exact integer sums are present).
+    ``float_dtype`` is the float payloads' accumulator dtype: the probe
+    runs in it, and f32 and f64 shapes keep separate entries."""
     assert len(candidates) > 0
     platform = mesh.devices.flat[0].platform
-    prior = heuristic_strategy(platform, num_segments, n_payload)
+    float_dtype = np.dtype(float_dtype)
+    prior = heuristic_strategy(
+        platform, num_segments, n_payload, float_dtype
+    )
     if prior not in candidates:
         prior = candidates[0]
     if len(candidates) == 1 or not autotune_enabled(
@@ -192,13 +214,15 @@ def choose_strategy(
         _bucket(probe_n),
         _bucket(num_segments),
         _bucket(n_payload),
+        float_dtype.name,
         tuple(candidates),
     )
     hit = _TUNE_CACHE.get(key)
     if hit is not None:
         return hit
     winner = _measure(
-        mesh, probe_n, num_segments, n_payload, list(candidates), prior, log
+        mesh, probe_n, num_segments, n_payload, list(candidates), prior, log,
+        float_dtype,
     )
     _TUNE_CACHE[key] = winner
     return winner
@@ -212,11 +236,12 @@ def _measure(
     candidates: List[str],
     prior: str,
     log: Optional[Any],
+    float_dtype: Any,
 ) -> str:
     """Time each candidate kernel on an ``n``-row synthetic probe on the
-    mesh's first device; best-of-2 after a compile/warm run. Any failure
-    (OOM, missing dtype support) falls back to the prior — tuning must
-    never break the query."""
+    mesh's first device, float payloads in ``float_dtype``; best-of-2
+    after a compile/warm run. Any failure (OOM, missing dtype support)
+    falls back to the prior — tuning must never break the query."""
     import jax
     import jax.numpy as jnp
 
@@ -229,11 +254,12 @@ def _measure(
         with jax.default_device(dev):
             seg = jnp.asarray(seg_np)
             fpay = [
-                jnp.asarray(rng.random(n).astype(np.float32))
+                jnp.asarray(rng.random(n).astype(float_dtype))
                 for _ in range(nf)
             ]
             cpay = [jnp.ones((n,), jnp.bool_)]
         best, best_t = prior, float("inf")
+        times: Dict[str, float] = {}
 
         # payloads are jit ARGUMENTS, exactly like the production call
         # sites — closure-captured constants would let XLA fold casts and
@@ -256,14 +282,16 @@ def _measure(
                     t = min(t, time.perf_counter() - t0)
             except Exception:  # pragma: no cover - kernel unsupported
                 continue
+            times[strat] = t
             if t < best_t:
                 best, best_t = strat, t
         if log is not None:
             log.info(
                 "fugue_tpu.jax segment-reduction autotune: %s wins at "
-                "rows~%d segments=%d payloads=%d on %s (%.2fms)",
-                best, n, num_segments, n_payload, dev.platform,
-                best_t * 1e3,
+                "rows~%d segments=%d payloads=%d %s on %s (%.2fms; %s)",
+                best, n, num_segments, n_payload, float_dtype.name,
+                dev.platform, best_t * 1e3,
+                " ".join(f"{k}={v * 1e3:.2f}ms" for k, v in times.items()),
             )
         return best
     except Exception:  # pragma: no cover - probe setup failed

@@ -18,7 +18,7 @@ from fugue_tpu.column.expressions import function
 from fugue_tpu.execution.native_execution_engine import NativeExecutionEngine
 from fugue_tpu.jax_backend import JaxExecutionEngine, groupby, segtune
 
-STRATS = ["matmul", "matmul_bf16", "scatter", "sort"]
+STRATS = ["matmul", "matmul_bf16", "scatter", "sort", "masked"]
 
 
 def make_engine(**conf: Any) -> JaxExecutionEngine:
@@ -174,7 +174,9 @@ def test_selector_tier_and_size_routing():
     assert pinned._groupby_strategy(blocks, 64, 10, 3) == "matmul"
     # bf16 pin needs all-f32 payloads
     b16 = make_engine(**{"fugue.jax.groupby.strategy": "matmul_bf16"})
-    assert b16._groupby_strategy(blocks, 64, 10, 3, all_f32=False) is None
+    assert b16._groupby_strategy(
+        blocks, 64, 10, 3, float_dtype=np.float64
+    ) is None
     assert b16._groupby_strategy(blocks, 64, 10, 3) == "matmul_bf16"
     # over every cap: no packed strategy at all
     assert (
@@ -219,6 +221,138 @@ def test_autotune_cache_is_one_shot():
     )
     assert segtune._TUNE_RUNS["count"] == before + 1
     segtune.clear_cache()
+
+
+def _q1_frame(n: int = 5000) -> pd.DataFrame:
+    rng = np.random.default_rng(11)
+    pdf = pd.DataFrame(
+        {
+            "flag": rng.choice(["A", "N", "R"], n),
+            "status": rng.choice(["F", "O"], n),
+            "qty": rng.integers(1, 51, n).astype(np.float64),
+            "price": rng.random(n) * 1e5,
+            "disc": rng.integers(0, 11, n) / 100.0,
+            "ship": rng.integers(0, 100, n).astype(np.int64),
+        }
+    )
+    pdf.loc[rng.random(n) < 0.05, "flag"] = None  # the null bucket
+    return pdf
+
+
+def test_masked_q1_shape_matches_numpy_f64():
+    """A TPC-H Q1-shaped group-by on the masked kernel: two string keys
+    (one with nulls), f64 sums and averages and COUNT(*), after a filter
+    whose dropped rows carry the out-of-range sentinel — checked against
+    numpy float64."""
+    pdf = _q1_frame()
+    e = make_engine(**{"fugue.jax.groupby.strategy": "masked"})
+    jdf = e.filter(e.to_df(pdf), col("ship") <= 80)
+    out = e.aggregate(
+        jdf, PartitionSpec(by=["flag", "status"]),
+        [
+            ff.sum(col("qty")).alias("sum_qty"),
+            ff.sum(col("price") * (1 - col("disc"))).alias("sum_disc"),
+            ff.avg(col("price")).alias("avg_price"),
+            ff.count(col("*")).alias("n"),
+        ],
+    ).as_pandas()
+    assert e.fallbacks == {}, e.fallbacks
+    assert e.strategy_counts.get("masked", 0) == 1, e.strategy_counts
+    kept = pdf[pdf["ship"] <= 80].assign(
+        flag=lambda d: d["flag"].fillna("~"),
+        disc_price=lambda d: d["price"] * (1 - d["disc"]),
+    )
+    exp = kept.groupby(["flag", "status"]).agg(
+        sum_qty=("qty", "sum"), sum_disc=("disc_price", "sum"),
+        avg_price=("price", "mean"), n=("qty", "size"),
+    ).reset_index().sort_values(["flag", "status"]).reset_index(drop=True)
+    got = out.assign(flag=out["flag"].fillna("~")).sort_values(
+        ["flag", "status"]
+    ).reset_index(drop=True)
+    assert got[["flag", "status"]].values.tolist() == \
+        exp[["flag", "status"]].values.tolist()
+    assert got["n"].tolist() == exp["n"].tolist()
+    for c in ("sum_qty", "sum_disc", "avg_price"):
+        np.testing.assert_allclose(
+            got[c].to_numpy(np.float64), exp[c].to_numpy(np.float64),
+            rtol=1e-12,
+        )
+
+    # the kernel itself: sentinel bins (>= num_segments) contribute nothing
+    rng = np.random.default_rng(5)
+    seg = rng.integers(0, 8, 1000).astype(np.int32)  # 6 bins + sentinels
+    v = rng.random(1000) * 1e6
+    f, c = groupby.masked_segment_sums([v], [np.ones(1000, bool)], seg, 6)
+    assert f[0].dtype == np.float64 and c[0].dtype == np.int32
+    np.testing.assert_allclose(
+        np.asarray(f[0]),
+        [v[seg == s].sum() for s in range(6)],
+        rtol=1e-12,
+    )
+    assert np.asarray(c[0]).tolist() == [
+        int((seg == s).sum()) for s in range(6)
+    ]
+
+
+def test_selector_masked_for_f64_only():
+    """masked is the accelerator prior for f64 payloads over few bins; f32
+    keeps the MXU matmul, CPU meshes keep scatter, and past the cap f64
+    takes another kernel. On the engine, auto offers masked for f64 only,
+    and a pin takes it for any float payloads."""
+    cap = groupby._MASKED_MAX_SEGMENTS
+    assert segtune.heuristic_strategy("tpu", 6, 9, np.float64) == "masked"
+    assert segtune.heuristic_strategy("tpu", cap, 9, np.float64) == "masked"
+    assert segtune.heuristic_strategy("tpu", 6, 9, np.float32) == "matmul"
+    assert segtune.heuristic_strategy("tpu", 6, 9) == "matmul"
+    assert segtune.heuristic_strategy("cpu", 6, 9, np.float64) == "scatter"
+    assert segtune.heuristic_strategy(
+        "tpu", cap + 1, 9, np.float64
+    ) != "masked"
+
+    e = make_engine()
+    blocks = e.to_df(_frame(64)).blocks
+    # CPU mesh auto: scatter whatever the float dtype
+    assert e._groupby_strategy(
+        blocks, 64, 6, 9, float_dtype=np.float64
+    ) == "scatter"
+    pinned = make_engine(**{"fugue.jax.groupby.strategy": "masked"})
+    assert pinned._groupby_strategy(
+        blocks, 64, 6, 9, float_dtype=np.float64
+    ) == "masked"
+    assert pinned._groupby_strategy(blocks, 64, 6, 9) == "masked"
+    # exact-int payloads and more bins than the cap exclude it
+    assert pinned._groupby_strategy(blocks, 64, 6, 9, need_int=True) is None
+    assert pinned._groupby_strategy(
+        blocks, 64, cap + 1, 9, float_dtype=np.float64
+    ) is None
+
+
+def test_autotune_key_has_float_dtype():
+    """One shape probed in f32 and in f64 gives two probes and two cache
+    entries; repeating either is a cache hit."""
+    e = make_engine()
+    mesh = e.to_df(_frame(64)).blocks.mesh
+    cands = ["matmul", "masked", "scatter", "sort"]
+    segtune.clear_cache()
+    before = segtune._TUNE_RUNS["count"]
+    try:
+        picks = {}
+        for dt in (np.float32, np.float64):
+            picks[dt] = segtune.choose_strategy(
+                mesh, 1 << 15, 6, 6, cands, autotune_conf=True,
+                float_dtype=dt,
+            )
+            assert picks[dt] in cands
+        assert segtune._TUNE_RUNS["count"] == before + 2
+        assert len(segtune._TUNE_CACHE) == 2
+        for dt in (np.float32, np.float64):
+            assert segtune.choose_strategy(
+                mesh, 1 << 15, 6, 6, cands, autotune_conf=True,
+                float_dtype=dt,
+            ) == picks[dt]
+        assert segtune._TUNE_RUNS["count"] == before + 2
+    finally:
+        segtune.clear_cache()
 
 
 @pytest.mark.parametrize("strat", ["matmul", "scatter", "sort"])
