@@ -16,7 +16,9 @@ its pool from a sentence grammar over its word lists; here the pool's
 words are drawn uniformly from those lists (the same alphabet, word
 lengths and near-unique slices; the configuration lists it under
 ``assumed``). The comment draws from a stream of its own, so the other
-columns of a seed are what they were without it.
+columns of a seed are what they were without it. Its text is one arrow
+chunk while it is under ``CHUNK_BYTES`` (SF10 holds 1.6 GB) and several
+chunks beyond, cut at row boundaries.
 """
 
 from concurrent.futures import ThreadPoolExecutor
@@ -35,6 +37,9 @@ THREADS = 8  # numpy's gathers release the GIL: the comment is built in parallel
 # dbgen's text: TEXT_POOL_SIZE, and L_COMMENT's [10, 43] characters
 POOL_BYTES = 300 * 1024 * 1024
 COMMENT_LEN = (10, 43)
+# an arrow ``string`` array's text is under 2 GiB (int32 offsets): a larger
+# ``l_comment`` (SF30 holds ~4.8 GB) comes out in chunks under this limit
+CHUNK_BYTES = 2**31
 # dbgen's word lists (dists.dss: nouns, verbs, adjectives, adverbs,
 # prepositions, auxiliaries) and its sentence terminators
 WORDS = (
@@ -109,7 +114,28 @@ def _text_pool(rng: np.random.Generator, size: int) -> np.ndarray:
     return pool[:size]
 
 
-def _comments(rng: np.random.Generator, rows: int) -> pa.Array:
+def _string_chunks(offsets: np.ndarray, data: np.ndarray) -> pa.ChunkedArray:
+    """Arrow ``string`` chunks over ``data`` cut at row ``offsets`` (int64),
+    each chunk's text under ``CHUNK_BYTES``: one chunk whenever it all fits."""
+    rows = len(offsets) - 1
+    chunks = []
+    a = 0
+    while a < rows:
+        # the last row end that keeps this chunk's text under the limit
+        b = min(int(np.searchsorted(offsets, offsets[a] + CHUNK_BYTES)) - 1, rows)
+        if b <= a:
+            raise ValueError(f"row {a} alone exceeds the chunk limit of {CHUNK_BYTES} bytes")
+        lo, hi = int(offsets[a]), int(offsets[b])
+        chunks.append(pa.Array.from_buffers(
+            pa.string(), b - a,
+            [None, pa.py_buffer((offsets[a : b + 1] - lo).astype(np.int32)),
+             pa.py_buffer(data[lo:hi])],
+        ))
+        a = b
+    return pa.chunked_array(chunks, type=pa.string())
+
+
+def _comments(rng: np.random.Generator, rows: int) -> pa.ChunkedArray:
     """dbgen's ``dbg_text``: ``pool[start : start + length]`` for a length
     uniform in ``COMMENT_LEN`` and a start uniform over the pool."""
     lo, hi = COMMENT_LEN
@@ -118,8 +144,6 @@ def _comments(rng: np.random.Generator, rows: int) -> pa.Array:
     starts = rng.integers(0, POOL_BYTES - lengths + 1, dtype=np.int64)
     offsets = np.zeros(rows + 1, dtype=np.int64)
     np.cumsum(lengths, out=offsets[1:])
-    if offsets[-1] >= 2**31:
-        raise ValueError("comments exceed the 2 GiB of an arrow string array")
     # row ``s`` of ``windows`` is the ``hi`` bytes of the pool from ``s``
     windows = np.lib.stride_tricks.as_strided(pool, (POOL_BYTES, hi), (1, 1), writeable=False)
     keep = np.arange(hi, dtype=np.int32)
@@ -132,10 +156,7 @@ def _comments(rng: np.random.Generator, rows: int) -> pa.Array:
 
     with ThreadPoolExecutor(THREADS) as ex:
         list(ex.map(fill, range(0, rows, 1_000_000)))
-    return pa.Array.from_buffers(
-        pa.string(), rows,
-        [None, pa.py_buffer(offsets.astype(np.int32)), pa.py_buffer(data)],
-    )
+    return _string_chunks(offsets, data)
 
 
 def _numeric(values: np.ndarray) -> pa.Array:

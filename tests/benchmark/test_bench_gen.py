@@ -71,6 +71,45 @@ def test_orders_and_line_numbers(table):
     assert ((df.l_orderkey - 1) % 32 < 8).all()  # sparse keys: 8 of every 32
 
 
+def _chunk_ends(col):
+    """Each chunk's last offset: the bytes of text it holds."""
+    return [int(np.frombuffer(c.buffers()[1], np.int32)[c.offset + len(c)]) for c in col.chunks]
+
+
+def test_comment_chunks_under_the_limit(table, monkeypatch):
+    """Past ``CHUNK_BYTES`` the comment comes out in chunks, each under the
+    limit, that concatenate to the values of one chunk; no other column
+    changes."""
+    monkeypatch.setattr(lineitem, "CHUNK_BYTES", 50_000)
+    chunked = lineitem.generate(ROWS, SF, 2**31 + 17)
+    col = chunked.column("l_comment")
+    ends = _chunk_ends(col)
+    assert col.num_chunks == 16 and sum(ends) == _chunk_ends(table.column("l_comment"))[0]
+    assert max(ends) < 50_000
+    assert min(ends[:-1]) > 50_000 - lineitem.COMMENT_LEN[1]  # each cut as late as fits
+    assert table.column("l_comment").num_chunks == 1
+    assert col.combine_chunks().equals(table.column("l_comment").chunk(0))
+    assert chunked.equals(table)
+
+
+def test_comment_is_one_chunk_of_the_same_text_at_the_default_limit():
+    """Under the 2 GiB limit (SF1 and SF10) the comment is one chunk with
+    the values the generator has always made for this seed."""
+    import hashlib
+
+    col = lineitem.generate(3_001, 0.0005, 2**31 + 17).column("l_comment")
+    assert lineitem.CHUNK_BYTES == 2**31
+    assert col.num_chunks == 1 and _chunk_ends(col) == [80_111]
+    digest = hashlib.sha256(b"".join(v.encode() + b"\n" for v in col.to_pylist()))
+    assert digest.hexdigest() == "94531b0a71435dbf38548e4587426b523bc4e24ff5fd6afcaaf248eda9f59d0c"
+
+
+def test_a_row_longer_than_the_limit_is_an_error(monkeypatch):
+    monkeypatch.setattr(lineitem, "CHUNK_BYTES", lineitem.COMMENT_LEN[1] - 1)
+    with pytest.raises(ValueError, match="exceeds the chunk limit"):
+        lineitem.generate(200, 0.0001, 1)
+
+
 def test_comment_is_a_slice_of_the_text_pool(table):
     """dbgen's L_COMMENT: 10 to 43 characters of the seeded word pool,
     near one distinct value a row; the other columns do not depend on it."""

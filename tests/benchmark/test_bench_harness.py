@@ -11,7 +11,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench_tiny import REPO, run_tiny, tiny_root
+from bench_tiny import REPO, add_config, assert_unchanged, run_tiny, snapshot, tiny_root
 
 
 @pytest.fixture(scope="module")
@@ -64,17 +64,9 @@ def test_traced_run_on_cpu_has_no_device_numbers(root):
 def test_new_config_mix_and_metric_from_files_alone(tmp_path):
     root = tiny_root(tmp_path)
     bench = os.path.join(root, "benchmark")
-    before = {}
-    for d, _, files in os.walk(bench):
-        for f in files:
-            p = os.path.join(d, f)
-            with open(p, "rb") as fh:
-                before[p] = fh.read()
-    with open(os.path.join(bench, "configs", "tpch_sf10.json")) as f:
-        config = json.load(f)
-    config["name"] = "tpch_tiny_extra"
-    with open(os.path.join(bench, "configs", "tpch_tiny_extra.json"), "w") as f:
-        json.dump(config, f)
+    before = snapshot(root)
+    add_config(root, "tpch_tiny_extra",
+               {"name": "tpch_tiny_extra.q6_then_q1", "traffic": "q6_then_q1", "chips": 1})
     with open(os.path.join(bench, "traffic", "q6_then_q1.json"), "w") as f:
         json.dump({"streams": 1, "queries": ["q6", "q1"]}, f)
     with open(os.path.join(bench, "metrics", "answers_per_s.py"), "w") as f:
@@ -82,10 +74,6 @@ def test_new_config_mix_and_metric_from_files_alone(tmp_path):
     spec_path = os.path.join(root, "BENCHMARK.json")
     with open(spec_path) as f:
         spec = json.load(f)
-    spec["configs"].append(dict(spec["configs"][0], name="tpch_tiny_extra",
-                                file="benchmark/configs/tpch_tiny_extra.json"))
-    spec["workloads"].append({"name": "tpch_tiny_extra.q6_then_q1", "config": "tpch_tiny_extra",
-                              "traffic": "q6_then_q1", "chips": 1, "why": "test"})
     spec["end_to_end"].append({"name": "answers_per_s", "unit": "1/s", "better": "higher",
                                "bound": 0.05, "source": "host_clock",
                                "workloads": ["tpch_tiny_extra.q6_then_q1"]})
@@ -95,9 +83,49 @@ def test_new_config_mix_and_metric_from_files_alone(tmp_path):
     assert res["correct"], res["checks"]
     assert res["metrics"]["answers_per_s"]["value"] > 0
     assert {"q1.max_rel_err", "q6.max_rel_err"} <= set(res["checks"])
-    for p, data in before.items():  # no file that was there changed
-        with open(p, "rb") as fh:
-            assert fh.read() == data, p
+    assert_unchanged(before)  # no file that was there changed
+
+
+def test_four_chip_config_from_files_alone(tmp_path, monkeypatch):
+    """A four-chip configuration and cell added as new files run through
+    ``run_cell`` on four of the suite's virtual CPU devices, with
+    ``l_comment`` in several chunks: every frame sits on a four-device
+    mesh, nothing falls back to the host, and the answers match
+    ``reference/q1.py``."""
+    from benchmark import run
+
+    rows = 48_000  # above the engine's 32,768-row floor for auto-shuffle
+    root = tiny_root(tmp_path, rows=rows)
+    before = snapshot(root)
+    with open(os.path.join(root, "benchmark", "configs", "tpch_sf10.json")) as f:
+        conf = json.load(f)["engine_conf"]
+    config = add_config(root, "tpch_4chip",
+                        {"name": "tpch_4chip.q1", "traffic": "q1", "chips": 4},
+                        chips=4, engine_conf=dict(conf, **{"fugue.jax.devices": "0,1,2,3"}))
+
+    gen = run.plugin("gen", "lineitem", root)
+    gen.CHUNK_BYTES = 200_000  # ~1.3 MB of comments: several chunks
+    assert gen.generate(rows, config["scale_factor"], 5).column("l_comment").num_chunks > 4
+    driver = run.plugin("drivers", "embedded", root)
+    meshes = []
+
+    def check_engine(engine, frames, platform):
+        frames = list(frames)
+        meshes.extend(int(df.native.mesh.devices.size) for df in frames)
+        return real_check(engine, frames, platform)
+
+    real_check = driver.check_engine
+    monkeypatch.setattr(driver, "check_engine", check_engine)
+    lines = []
+    res = run.run_cell("tpch_4chip.q1", 5, 0.3, require_tpu=False, root=root,
+                       log=lambda s: None, out=lines.append)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert res["checks"]["answers_compared"]["value"] == res["attempted"]
+    assert meshes and set(meshes) == {4}
+    counters = next(json.loads(s)["counters"] for s in lines if s.startswith('{"counters"'))
+    assert counters["after"]["fallbacks"] == {}
+    assert_unchanged(before)
 
 
 def test_refuses_the_cpu():

@@ -57,6 +57,31 @@ def test_trace_metrics(summary):
     assert roof == pytest.approx(0.16705147289498776, rel=1e-12)
 
 
+@pytest.mark.parametrize("devices", [1, 4])
+def test_roofline_counts_every_chip_that_ran(devices):
+    """Rows sharded over ``devices`` chips each read their share at one
+    chip's bandwidth: four chips read a quarter of one chip's share of the
+    same busy time, and one chip reads bytes / bandwidth / busy."""
+    from benchmark import run
+
+    bw = run.device_peaks("TPU v5 lite")["hbm_bytes_per_s"]
+    records = [{"query": "q1", "ok": True}] * 3 + [{"query": "q6", "ok": False}]
+    query_bytes = {"q1": 4.5e9, "q6": 1.4e9}
+    busy = 0.25
+
+    def reading(n):
+        ctx = run.RunContext(
+            trace={"window_s": 1.0, "busy_s": busy, "devices": n},
+            records=records, query_bytes=query_bytes,
+            peaks=run.device_peaks("TPU v5 lite"),
+        )
+        return run.plugin("metrics", "query_roofline_pct").read(ctx)
+
+    one = 100.0 * 3 * 4.5e9 / bw / busy  # the failed q6 reads nothing
+    assert reading(1) == pytest.approx(one, rel=1e-15)
+    assert reading(devices) == pytest.approx(one / devices, rel=1e-15)
+
+
 def test_union_and_labels():
     from benchmark import trace
 
