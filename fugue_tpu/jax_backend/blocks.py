@@ -7,7 +7,9 @@ sharded jax.Array blocks on a TPU pod mesh"):
 - timestamp             -> int64 microseconds since epoch
 - date                  -> int32 days since epoch
 - string                -> dictionary-encoded: int32 codes on device, the
-                           dictionary (np object array) on host
+                           dictionary on host (a ``HostDictionary``: the
+                           arrow array ingest built, Python strings only
+                           once a reader asks for them)
 - anything else (nested, binary, decimal) -> host arrow column
 
 Rows are padded to a multiple of the mesh size. Row membership has TWO
@@ -32,9 +34,13 @@ collectives (scaling-book recipe: pick a mesh, annotate shardings, let XLA
 do the rest).
 """
 
+import hashlib
 import logging
+import os
 import weakref
-from typing import Any, Dict, List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +49,7 @@ import pandas as pd
 import pyarrow as pa
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fugue_tpu.obs.trace import start_span
+from fugue_tpu.obs.trace import activate, current_span, start_span
 from fugue_tpu.schema import Schema
 from fugue_tpu.testing.retrace import active_retrace_sentinel
 from fugue_tpu.utils.assertion import assert_or_throw
@@ -71,12 +77,88 @@ def ensure_x64() -> None:
         jax.config.update("jax_enable_x64", True)
 
 
+class HostDictionary:
+    """The decode table of a string column, on the host.
+
+    Held as given: the arrow array ingest built (``large_string`` where
+    its text may pass 2 GiB) or a numpy object array. ``values``, the
+    numpy object array every reader of the table uses, is built from the
+    arrow array on first use and kept, so a column no query decodes or
+    compares as text never builds a Python string. Columns derived from
+    one column share its ``HostDictionary``, and with it that one build
+    and the ``fingerprint``."""
+
+    __slots__ = ("_arrow", "_values", "_fp")
+
+    def __init__(self, data: Any):
+        self._arrow: Optional[pa.Array] = data if isinstance(data, pa.Array) else None
+        self._values: Optional[np.ndarray] = None if self._arrow is not None else data
+        self._fp: Optional[int] = None
+
+    def __len__(self) -> int:
+        return len(self._arrow) if self._arrow is not None else len(self._values)
+
+    @property
+    def values(self) -> np.ndarray:
+        """The decode table as a numpy object array of ``str``."""
+        if self._values is None:
+            self._values = self._arrow.to_numpy(zero_copy_only=False)
+        return self._values
+
+    @property
+    def arrow(self) -> pa.Array:
+        """The decode table as an arrow string array."""
+        if self._arrow is None:
+            self._arrow = pa.array(self._values, type=pa.string())
+        return self._arrow
+
+    @property
+    def fingerprint(self) -> int:
+        """A deterministic 64-bit digest of the table's content (never
+        builtin ``hash``, which is salted per process). An arrow-backed
+        table digests its offsets and text without building a string."""
+        if self._fp is None:
+            if self._values is None:
+                arr = self._arrow.cast(pa.large_string())
+                offsets = np.frombuffer(arr.buffers()[1], np.int64)[
+                    arr.offset : arr.offset + len(arr) + 1
+                ]
+                text = arr.buffers()[2]
+                lo, hi = (int(offsets[0]), int(offsets[-1])) if len(arr) else (0, 0)
+                parts = [memoryview(offsets - lo).cast("B")]
+                if text is not None:
+                    parts.append(memoryview(text)[lo:hi])
+            else:
+                parts = [memoryview("\x00".join(str(x) for x in self._values).encode())]
+            self._fp = int.from_bytes(_digest(parts)[:8], "big")
+        return self._fp
+
+
+_DIGEST_PIECE = 1 << 26
+
+
+def _digest(parts: List[memoryview]) -> bytes:
+    """SHA-256 over the SHA-256 of each 64 MiB piece of ``parts``, the
+    pieces hashed on threads (hashlib releases the GIL on large inputs)."""
+    pieces = [p[i : i + _DIGEST_PIECE] for p in parts for i in range(0, len(p), _DIGEST_PIECE)]
+    with ThreadPoolExecutor(_ENCODE_THREADS) as ex:
+        digests = list(ex.map(lambda piece: hashlib.sha256(piece).digest(), pieces))
+    return hashlib.sha256(b"".join(digests)).digest()
+
+
+def _host_dictionary(data: Any) -> Optional[HostDictionary]:
+    if data is None or isinstance(data, HostDictionary):
+        return data
+    return HostDictionary(data)
+
+
 class JaxColumn:
     """One column: device data + optional mask, or a host arrow fallback.
 
     ``stats`` is an optional host-known (min, max) int pair bounding the
     VALID values of an integer-like column (a superset bound is fine);
-    ``dictionary`` holds the decode table for string columns. ``unique``
+    ``host_dict`` holds the decode table for string columns, and
+    ``dictionary`` reads it as a numpy object array. ``unique``
     is a host-known guarantee that the column's VALID values are
     pairwise distinct (captured at ingest for strictly monotonic integer
     keys — the dimension-table surrogate-key pattern); it stays sound
@@ -88,16 +170,26 @@ class JaxColumn:
         pa_type: pa.DataType,
         data: Any,  # jax.Array (device kinds) or pa.ChunkedArray (host kind)
         mask: Optional[Any] = None,  # jax bool array, True = valid
-        dictionary: Optional[np.ndarray] = None,  # for string kind
+        dictionary: Any = None,  # string kind: HostDictionary, arrow or numpy
         stats: Optional[Tuple[int, int]] = None,  # host-known (min, max)
         unique: bool = False,
     ):
         self.pa_type = pa_type
         self.data = data
         self.mask = mask
-        self.dictionary = dictionary
+        self.host_dict = _host_dictionary(dictionary)
         self.stats = stats
         self.unique = unique
+
+    @property
+    def dictionary(self) -> Optional[np.ndarray]:
+        """The decode table as a numpy object array (built once per
+        ``HostDictionary``); None for a column that is not a string."""
+        return None if self.host_dict is None else self.host_dict.values
+
+    @dictionary.setter
+    def dictionary(self, value: Any) -> None:
+        self.host_dict = _host_dictionary(value)
 
     @property
     def on_device(self) -> bool:
@@ -105,7 +197,7 @@ class JaxColumn:
 
     @property
     def is_string(self) -> bool:
-        return self.dictionary is not None
+        return self.host_dict is not None
 
     def with_data(
         self, data: Any, mask: Optional[Any], keep_stats: bool = True
@@ -116,7 +208,7 @@ class JaxColumn:
             self.pa_type,
             data,
             mask,
-            self.dictionary,
+            self.host_dict,
             self.stats if keep_stats else None,
         )
 
@@ -403,77 +495,257 @@ def decode_device_values(arr: Any, tp: pa.DataType) -> np.ndarray:
 
 def from_arrow(table: pa.Table, schema: Schema, mesh: Mesh) -> JaxBlocks:
     """Arrow -> device blocks (pads rows, encodes strings, builds masks,
-    captures host-side key stats)."""
+    captures host-side key stats). Chunked columns are read chunk by
+    chunk, never combined into one arrow array past its 2 GiB offsets.
+    Spanned as ``engine.ingest``, with an ``engine.ingest.strings`` child
+    per string column."""
+    with start_span(
+        "engine.ingest",
+        rows=table.num_rows,
+        columns=len(schema.fields),
+        chunks=sum(table.column(f.name).num_chunks for f in schema.fields),
+        bytes=table.nbytes,
+        devices=int(mesh.devices.size),
+    ):
+        return _from_arrow(table, schema, mesh)
+
+
+def _from_arrow(table: pa.Table, schema: Schema, mesh: Mesh) -> JaxBlocks:
     ensure_x64()
     ndev = mesh.devices.size
     n = table.num_rows
     pad_n = padded_len(n, ndev)
     sharding = row_sharding(mesh)
-    cols: Dict[str, JaxColumn] = {}
-    for field in schema.fields:
-        arr = table.column(field.name)
-        tp = field.type
-        if not is_device_type(tp):
-            cols[field.name] = JaxColumn(tp, arr.combine_chunks())
-            continue
-        if pa.types.is_string(tp) or pa.types.is_large_string(tp):
-            enc = arr.combine_chunks().dictionary_encode()
-            codes_np = enc.indices.to_numpy(zero_copy_only=False)
-            valid = ~pd.isna(codes_np)
-            codes = np.where(valid, np.nan_to_num(codes_np, nan=0), 0).astype(
-                np.int32
-            )
-            dictionary = np.asarray(enc.dictionary.to_pylist(), dtype=object)
-            data = _pad(codes, pad_n, 0)
-            mask = _pad(valid.astype(np.bool_), pad_n, False)
-            cols[field.name] = JaxColumn(
-                tp,
-                put_sharded(data, sharding),
-                put_sharded(mask, sharding),
-                dictionary,
-                stats=(0, max(len(dictionary) - 1, 0)),
-            )
-            continue
-        np_dtype = _np_dtype_for(tp)
-        combined = arr.combine_chunks()
-        null_count = combined.null_count
-        values = decode_device_values(combined, tp)
-        if null_count > 0:
-            import pyarrow.compute as pc
+    strings = [
+        f.name for f in schema.fields
+        if is_device_type(f.type)
+        and (pa.types.is_string(f.type) or pa.types.is_large_string(f.type))
+    ]
+    parent = current_span()
 
-            valid = pc.is_valid(combined).to_numpy(zero_copy_only=False)
-            # int columns with nulls surface as float+NaN from to_numpy
-            if values.dtype.kind == "f" and not np.issubdtype(
-                np_dtype, np.floating
-            ):
-                values = np.nan_to_num(values)
-            filled = np.where(valid, values, 0).astype(np_dtype)
-            mask_arr: Optional[Any] = put_sharded(
-                _pad(valid.astype(np.bool_), pad_n, False), sharding
-            )
-            data = _pad(filled, pad_n, 0)
-            stats = _int_like_stats(filled, tp)
-        else:
-            mask_arr = None
-            data = _pad(np.ascontiguousarray(values, dtype=np_dtype), pad_n, 0)
-            stats = _int_like_stats(data[:n] if n > 0 else data[:0], tp)
-        unique = False
-        if (
-            mask_arr is None
-            and pa.types.is_integer(tp)
-            and 0 < n <= _UNIQUE_CHECK_MAX
-        ):
-            # strictly monotonic integer keys (the dim-table surrogate-key
-            # pattern) are provably unique — unlocks the sync-free
-            # unique-right join fast path (relational.expand_join).
-            # element-wise comparison, NOT np.diff: subtraction wraps for
-            # unsigned/extreme values and would falsely prove uniqueness
-            unique = bool((data[1:n] > data[: n - 1]).all())
-        cols[field.name] = JaxColumn(
-            tp, put_sharded(data, sharding), mask_arr, stats=stats,
-            unique=unique,
-        )
+    def encode(name: str) -> Tuple[np.ndarray, np.ndarray, pa.Array]:
+        with activate(parent), start_span("engine.ingest.strings", column=name) as sp:
+            out = _encode_strings(table.column(name), pad_n)
+            if sp:
+                sp.set_attr(dictionary_size=len(out[2]))
+            return out
+
+    def ingest(encoded: Dict[str, Callable[[], Any]]) -> Dict[str, JaxColumn]:
+        return {
+            f.name: _ingest_column(table.column(f.name), f.type, encoded.get(f.name),
+                                   pad_n, sharding)
+            for f in schema.fields
+        }
+
+    if n < _PARALLEL_ENCODE_ROWS:
+        cols = ingest({name: partial(encode, name) for name in strings})
+    else:
+        # each string column encodes on a thread of its own while the
+        # other columns go to the device
+        with ThreadPoolExecutor(max(1, len(strings))) as ex:
+            cols = ingest({name: ex.submit(encode, name).result for name in strings})
     return JaxBlocks(n, cols, mesh)
+
+
+def _ingest_column(
+    arr: pa.ChunkedArray,
+    tp: pa.DataType,
+    encoded: Optional[Callable[[], Any]],
+    pad_n: int,
+    sharding: NamedSharding,
+) -> JaxColumn:
+    """One column of ``_from_arrow``; ``encoded`` gives a string column's
+    ``_encode_strings``."""
+    n = len(arr)
+    if not is_device_type(tp):
+        return JaxColumn(tp, host_array(arr))
+    if encoded is not None:
+        data, mask, dictionary = encoded()
+        return JaxColumn(
+            tp,
+            put_sharded(data, sharding),
+            put_sharded(mask, sharding),
+            dictionary,
+            stats=(0, max(len(dictionary) - 1, 0)),
+        )
+    np_dtype = _np_dtype_for(tp)
+    # one chunk is read in place; several are copied into one numpy
+    # array (fixed width: no offsets to overflow)
+    combined = arr.chunk(0) if arr.num_chunks == 1 else arr
+    null_count = combined.null_count
+    values = decode_device_values(combined, tp)
+    if null_count > 0:
+        import pyarrow.compute as pc
+
+        valid = pc.is_valid(combined).to_numpy(zero_copy_only=False)
+        # int columns with nulls surface as float+NaN from to_numpy
+        if values.dtype.kind == "f" and not np.issubdtype(np_dtype, np.floating):
+            values = np.nan_to_num(values)
+        filled = np.where(valid, values, 0).astype(np_dtype)
+        mask_arr: Optional[Any] = put_sharded(
+            _pad(valid.astype(np.bool_), pad_n, False), sharding
+        )
+        data = _pad(filled, pad_n, 0)
+        stats = _int_like_stats(filled, tp)
+    else:
+        mask_arr = None
+        data = _pad(np.ascontiguousarray(values, dtype=np_dtype), pad_n, 0)
+        stats = _int_like_stats(data[:n] if n > 0 else data[:0], tp)
+    unique = False
+    if mask_arr is None and pa.types.is_integer(tp) and 0 < n <= _UNIQUE_CHECK_MAX:
+        # strictly monotonic integer keys (the dim-table surrogate-key
+        # pattern) are provably unique — unlocks the sync-free
+        # unique-right join fast path (relational.expand_join).
+        # element-wise comparison, NOT np.diff: subtraction wraps for
+        # unsigned/extreme values and would falsely prove uniqueness
+        unique = bool((data[1:n] > data[: n - 1]).all())
+    return JaxColumn(tp, put_sharded(data, sharding), mask_arr, stats=stats, unique=unique)
+
+
+def host_array(arr: pa.ChunkedArray) -> Any:
+    """A host-kind column's storage: one arrow array, or the chunked
+    array as it came where combining would overflow 32-bit offsets."""
+    if arr.num_chunks == 1:
+        return arr.chunk(0)
+    try:
+        return arr.combine_chunks()
+    except pa.ArrowInvalid:
+        return arr
+
+
+# A string column of at least this many rows is encoded on several
+# threads (arrow's kernels release the GIL): in row blocks whose small
+# dictionaries are unified where the sample below finds few distinct
+# values, else in partitions by content whose dictionaries are disjoint.
+# From 2**18 rows on, both threaded encodes take at most the time of one
+# ``dictionary_encode`` on a near-unique and on a four-value column
+_PARALLEL_ENCODE_ROWS = 1 << 18
+_ENCODE_SAMPLE_ROWS = 1 << 16
+_ENCODE_THREADS = max(1, min(16, os.cpu_count() or 1))
+
+
+def _encode_strings(
+    arr: pa.ChunkedArray, pad_n: int
+) -> Tuple[np.ndarray, np.ndarray, pa.Array]:
+    """Dictionary-encode a string column chunk by chunk: ``(codes, valid,
+    dictionary)``, codes int32 and valid bool padded to ``pad_n`` (null
+    and padding rows hold code 0, invalid), the dictionary one arrow
+    array with each distinct value once. Several chunks encode as
+    ``large_string``, since their one dictionary may hold over 2 GiB of
+    text. The dictionary is in order of first occurrence, as one arrow
+    ``dictionary_encode`` gives it, except where partitions by content
+    encode the column: there it is by partition, then first occurrence.
+    The result never depends on how the rows are chunked."""
+    import pyarrow.compute as pc
+
+    n = len(arr)
+    if arr.num_chunks > 1 and pa.types.is_string(arr.type):
+        arr = arr.cast(pa.large_string())
+    codes = np.zeros(pad_n, dtype=np.int32)
+    valid = np.zeros(pad_n, dtype=np.bool_)
+    if arr.null_count:
+        valid[:n] = pc.is_valid(arr).to_numpy(zero_copy_only=False)
+    else:
+        valid[:n] = True
+    if n < _PARALLEL_ENCODE_ROWS:
+        enc = arr.dictionary_encode()
+        lo = 0
+        for chunk in enc.chunks:
+            codes[lo : lo + len(chunk)] = chunk.indices.fill_null(0).to_numpy()
+            lo += len(chunk)
+        dictionary = enc.chunk(0).dictionary if enc.num_chunks else pa.array([], arr.type)
+        return codes, valid, dictionary
+    sample = arr.slice(0, _ENCODE_SAMPLE_ROWS)
+    with ThreadPoolExecutor(_ENCODE_THREADS) as ex:
+        if pc.count_distinct(sample).as_py() * 4 <= len(sample):
+            return _encode_row_blocks(arr, codes, valid, ex)
+        return _encode_by_content(arr, codes, valid, ex)
+
+
+def _row_blocks(n: int) -> List[Tuple[int, int]]:
+    """``[lo, hi)`` bounds of one block of rows a thread."""
+    step = max(1, -(-n // _ENCODE_THREADS))
+    return [(lo, min(lo + step, n)) for lo in range(0, n, step)]
+
+
+def _encode_row_blocks(
+    arr: pa.ChunkedArray, codes: np.ndarray, valid: np.ndarray, ex: ThreadPoolExecutor
+) -> Tuple[np.ndarray, np.ndarray, pa.Array]:
+    """Few distinct values: each row block encodes on its own thread; the
+    blocks' dictionaries, concatenated in block order and encoded once,
+    give the dictionary in order of first occurrence and each block's
+    map from its codes to the column's."""
+    n = len(arr)
+    bounds = _row_blocks(n)
+    parts = list(ex.map(lambda b: _one_dictionary(arr.slice(b[0], b[1] - b[0])), bounds))
+    local_dicts = [d for d, _ in parts]
+    union = pa.chunked_array(local_dicts, type=local_dicts[0].type).dictionary_encode()
+    remap = np.concatenate(
+        [c.indices.to_numpy() for c in union.chunks] or [np.zeros(0, np.int32)]
+    )
+    dictionary = union.chunk(0).dictionary if union.num_chunks else local_dicts[0]
+    base = np.cumsum([0] + [len(d) for d in local_dicts])
+
+    def fill(i: int) -> None:
+        (lo, hi), (d, local) = bounds[i], parts[i]
+        if len(d):
+            codes[lo:hi] = remap[base[i] + local]
+        codes[lo:hi][~valid[lo:hi]] = 0
+
+    list(ex.map(fill, range(len(bounds))))
+    return codes, valid, dictionary
+
+
+def _one_dictionary(arr: pa.ChunkedArray) -> Tuple[pa.Array, np.ndarray]:
+    """``(dictionary, codes)`` of one slice, null rows at code 0."""
+    enc = arr.dictionary_encode()
+    if not enc.num_chunks:
+        return pa.array([], arr.type), np.zeros(0, np.int32)
+    local = np.concatenate([c.indices.fill_null(0).to_numpy() for c in enc.chunks])
+    return enc.chunk(0).dictionary, local
+
+
+def _encode_by_content(
+    arr: pa.ChunkedArray, codes: np.ndarray, valid: np.ndarray, ex: ThreadPoolExecutor
+) -> Tuple[np.ndarray, np.ndarray, pa.Array]:
+    """Many distinct values: rows go to partitions by their length and
+    first byte, so equal values share a partition; each partition encodes
+    on its own thread, and the column's dictionary is the partitions'
+    dictionaries one after another (disjoint by construction)."""
+    import pyarrow.compute as pc
+
+    n = len(arr)
+    parts = _ENCODE_THREADS
+    whole = arr.combine_chunks().cast(pa.large_string())  # 64-bit offsets
+    offsets = np.frombuffer(whole.buffers()[1], np.int64)[whole.offset : whole.offset + n + 1]
+    text = whole.buffers()[2]
+    data = np.frombuffer(text, np.uint8) if text is not None and text.size else None
+    key = np.empty(n, dtype=np.int16)
+
+    def partition(b: Tuple[int, int]) -> None:
+        lo, hi = b
+        lengths = offsets[lo + 1 : hi + 1] - offsets[lo:hi]
+        first = 0
+        if data is not None:
+            first = np.where(lengths > 0, data[np.minimum(offsets[lo:hi], data.size - 1)], 0)
+        key[lo:hi] = (lengths * 257 + first) % parts
+        key[lo:hi][~valid[lo:hi]] = -1  # null rows stay at code 0
+
+    def encode(p: int) -> Tuple[np.ndarray, pa.Array, np.ndarray]:
+        rows = np.flatnonzero(key == p)
+        d, local = _one_dictionary(pa.chunked_array([pc.take(whole, pa.array(rows))]))
+        return rows, d, local
+
+    list(ex.map(partition, _row_blocks(n)))
+    done = list(ex.map(encode, range(parts)))
+    base = np.cumsum([0] + [len(d) for _, d, _ in done])
+
+    def fill(p: int) -> None:
+        rows, _, local = done[p]
+        codes[rows] = local + base[p]
+
+    list(ex.map(fill, range(parts)))
+    return codes, valid, pa.concat_arrays([d for _, d, _ in done])
 
 
 _UNIQUE_CHECK_MAX = 4_000_000  # O(n) host check only for dim-table sizes
@@ -542,9 +814,7 @@ def _to_arrow(blocks: JaxBlocks, schema: Schema) -> pa.Table:
             indices = pa.array(
                 values.astype(np.int32, copy=False), mask=mask_np
             )
-            da = pa.DictionaryArray.from_arrays(
-                indices, pa.array(col.dictionary, type=pa.string())
-            )
+            da = pa.DictionaryArray.from_arrays(indices, col.host_dict.arrow)
             arrays.append(da.cast(tp))
             continue
         if pa.types.is_timestamp(tp):

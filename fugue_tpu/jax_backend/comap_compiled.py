@@ -187,7 +187,7 @@ def _concat_key_blocks_n(
                 _merged_stats(stats, c),
             )
         cols[k] = JaxColumn(
-            cs[0].pa_type, data[k], mask.get(k), cs[0].dictionary,
+            cs[0].pa_type, data[k], mask.get(k), cs[0].host_dict,
             stats.stats,
         )
     combined = JaxBlocks(None, cols, mesh, row_valid=row_valid)
@@ -302,7 +302,7 @@ def compiled_comap(
             names.append(name)
             if col.mask is not None:
                 array_args[f"m{m}:_{name}_mask"] = col.mask
-            if col.dictionary is not None:
+            if col.host_dict is not None:
                 st[f"_{name}_dict"] = col.dictionary
         array_args[f"m{m}:__seg"] = segs[m]
         static_args.append(st)

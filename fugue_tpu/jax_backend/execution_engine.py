@@ -227,7 +227,7 @@ class JaxMapEngine(MapEngine):
             array_args[name] = col.data
             if col.mask is not None:
                 array_args[f"_{name}_mask"] = col.mask
-            if col.dictionary is not None:
+            if col.host_dict is not None:
                 static_args[f"_{name}_dict"] = col.dictionary
         if seg is not None:
             array_args["_segment_ids"] = seg
@@ -271,7 +271,7 @@ class JaxMapEngine(MapEngine):
                 if f"_{f.name}_dict" in dict_stash:
                     continue
                 src = blocks.columns.get(passthrough.get(f.name, ""))
-                if src is None or src.dictionary is None:
+                if src is None or src.host_dict is None:
                     raise _StringDictUnavailable(f.name)
         out = jitted(
             array_args, blocks.row_valid, _nrows_arg(blocks)
@@ -346,11 +346,11 @@ class JaxMapEngine(MapEngine):
                 # must NOT carry the dictionary (to_arrow would decode codes
                 # into the wrong type); stats only describe integer-like
                 # value bounds (advisor r2, low)
-                if src.dictionary is not None and (
+                if src.host_dict is not None and (
                     pa.types.is_string(f.type)
                     or pa.types.is_large_string(f.type)
                 ):
-                    dictionary = src.dictionary
+                    dictionary = src.host_dict
                 if (
                     pa.types.is_integer(f.type)
                     or pa.types.is_boolean(f.type)
@@ -1472,7 +1472,7 @@ class JaxExecutionEngine(ExecutionEngine):
                     else None
                 )
                 dict_r = (
-                    src.dictionary
+                    src.host_dict
                     if src is not None
                     else (
                         expr_eval.result_dictionary(c, blocks)
@@ -2248,7 +2248,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 else None
             )
             dict_r = (
-                src.dictionary
+                src.host_dict
                 if src is not None
                 else (
                     expr_eval.result_dictionary(c, blocks)
@@ -2436,7 +2436,11 @@ class JaxExecutionEngine(ExecutionEngine):
         if compiled is not None:
             try:
                 t0 = time.perf_counter()
-                with start_span("engine.dispatch", program=name) as sp:
+                with start_span(
+                    "engine.dispatch",
+                    program=name,
+                    devices=int(self._mesh.devices.size),
+                ) as sp:
                     out = compiled(*args)
                     if sp:
                         sp.set_attr(compiled=0)
@@ -2599,7 +2603,9 @@ class JaxExecutionEngine(ExecutionEngine):
             except Exception:  # pragma: no cover - jax version drift
                 sizer = None
         t0 = time.perf_counter()
-        with start_span("engine.dispatch", program=name) as sp:
+        with start_span(
+            "engine.dispatch", program=name, devices=int(self._mesh.devices.size)
+        ) as sp:
             out = jitted(*args)
             compiled = False
             if sizer is not None:
@@ -3038,7 +3044,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 None if f"km:{k}" not in outs else jax.device_put(
                     outs[f"km:{k}"], sharding
                 ),
-                src_col.dictionary,
+                src_col.host_dict,
                 src_col.stats,
             )
         for name, func, arg, tp in typed_plans:
@@ -3642,7 +3648,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 None
                 if f"km:{k}" not in outs
                 else jax.device_put(outs[f"km:{k}"], sharding),
-                src_col.dictionary,
+                src_col.host_dict,
                 src_col.stats,
             )
         for name, func, arg, tp in typed_plans:

@@ -15,7 +15,17 @@ Because the lookup tables are baked into traced programs as constants,
 jit cache keys at the call sites must include ``dict_fingerprint``.
 """
 
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
+from typing import (
+    Any,
+    Dict,
+    Iterator,
+    List,
+    Mapping,
+    NamedTuple,
+    Optional,
+    Tuple,
+    Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -595,37 +605,43 @@ def finalize_string_result(
     return data, dictionary, (0, max(len(dictionary) - 1, 0))
 
 
-def dicts_of(blocks: JaxBlocks) -> Dict[str, np.ndarray]:
+class _Dictionaries(Mapping):
+    """The decode tables of a frame's device string columns by name, each
+    built (``JaxColumn.dictionary``) only when an expression reads it."""
+
+    def __init__(self, blocks: JaxBlocks):
+        self._cols = {
+            name: col
+            for name, col in blocks.columns.items()
+            if col.on_device and col.is_string
+        }
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        return self._cols[name].dictionary
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._cols)
+
+    def __len__(self) -> int:
+        return len(self._cols)
+
+
+def dicts_of(blocks: JaxBlocks) -> Mapping:
     """Decode tables of the device-resident string columns (host side)."""
-    return {
-        name: col.dictionary
-        for name, col in blocks.columns.items()
-        if col.on_device and col.is_string
-    }
+    return _Dictionaries(blocks)
 
 
 def dict_fingerprint(blocks: JaxBlocks) -> Tuple[Any, ...]:
     """A stable key component for jit caches of programs that bake
     string-dictionary lookup tables in as constants: same expression +
-    same fingerprint => identical program. Hashed with a DETERMINISTIC
-    digest (not builtin ``hash``, which is salted per process) so the
-    persistent executable cache recognizes the same dictionary across
-    process restarts."""
-    import hashlib
-
+    same fingerprint => identical program. Deterministic
+    (``HostDictionary.fingerprint``) so the persistent executable cache
+    recognizes the same dictionary across process restarts."""
     out = []
     for name in sorted(blocks.columns):
         col = blocks.columns[name]
         if col.on_device and col.is_string:
-            fp = getattr(col, "_dict_fp", None)
-            if fp is None:
-                digest = hashlib.blake2b(
-                    "\x00".join(str(x) for x in col.dictionary).encode(),
-                    digest_size=8,
-                ).digest()
-                fp = int.from_bytes(digest, "big")
-                col._dict_fp = fp  # type: ignore[attr-defined]
-            out.append((name, len(col.dictionary), fp))
+            out.append((name, len(col.host_dict), col.host_dict.fingerprint))
     return tuple(out)
 
 

@@ -398,13 +398,9 @@ def _stream_to_blocks(
     for f in schema.fields:
         tp = f.type
         if f.name in host_chunks:
-            chunks = host_chunks[f.name]
-            combined = (
-                pa.chunked_array(chunks, type=tp).combine_chunks()
-                if len(chunks) > 0
-                else pa.chunked_array([pa.array([], type=tp)]).combine_chunks()
+            out_cols[f.name] = B.JaxColumn(
+                tp, B.host_array(pa.chunked_array(host_chunks[f.name], type=tp))
             )
-            out_cols[f.name] = B.JaxColumn(tp, combined)
             continue
         data = _assemble(stagers[f.name], (pad_n,), sharding)
         mask = (
@@ -448,7 +444,7 @@ def _chunk_view(blocks: B.JaxBlocks, lo: int, hi: int) -> B.JaxBlocks:
                 col.pa_type,
                 col.data[lo:hi],
                 None if col.mask is None else col.mask[lo:hi],
-                col.dictionary,
+                col.host_dict,
                 stats=col.stats,
             )
         else:

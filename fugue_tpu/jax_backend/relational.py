@@ -197,7 +197,7 @@ def concat_key_blocks(
             c1.pa_type,
             data[k],
             mask.get(k),
-            c1.dictionary,
+            c1.host_dict,
             _merged_stats(c1, c2),
         )
     combined = JaxBlocks(None, cols, mesh, row_valid=row_valid)
@@ -620,7 +620,7 @@ def expand_join(
             f.type,
             jax.device_put(data, sharding),
             None if mask is None else jax.device_put(mask, sharding),
-            src.dictionary,
+            src.host_dict,
             src.stats,
         )
     out = JaxBlocks(M, cols, mesh)
@@ -719,13 +719,13 @@ def _unique_right_join(
                 f.type,
                 jax.device_put(g2[n], sharding),
                 jax.device_put(gm2[n], sharding),
-                src.dictionary,
+                src.host_dict,
                 src.stats,
             )
         else:
             src = b1.columns[n]
             cols[n] = JaxColumn(
-                f.type, src.data, src.mask, src.dictionary, src.stats,
+                f.type, src.data, src.mask, src.host_dict, src.stats,
                 unique=src.unique,
             )
     return JaxBlocks(
@@ -803,7 +803,7 @@ def _gather_right_unmatched(
                 f.type,
                 jax.device_put(g[n], sharding),
                 None if n not in gm else jax.device_put(gm[n], sharding),
-                src.dictionary,
+                src.host_dict,
                 src.stats,
             )
         else:
@@ -920,7 +920,7 @@ def repartition_by_key(
             jax.device_put(outs[f"m:{n}"], sharding)
             if n in mask_names
             else None,
-            src.dictionary,
+            src.host_dict,
             src.stats,
         )
     return JaxBlocks(
@@ -1010,7 +1010,7 @@ def union_all_blocks(b1: JaxBlocks, b2: JaxBlocks) -> JaxBlocks:
             c1.pa_type,
             data[n],
             mask.get(n),
-            c1.dictionary,
+            c1.host_dict,
             _merged_stats(c1, c2),
         )
     nrows = (
@@ -1218,7 +1218,7 @@ def device_fillna(
             src.pa_type,
             jax.device_put(outs[nm], sharding),
             None,
-            src.dictionary,
+            src.host_dict,
             src.stats,
         )
     return JaxBlocks(
@@ -2078,7 +2078,7 @@ def _window_frame_agg(
     )
     sharding = row_sharding(blocks.mesh)
     dictionary = None if vcol is None else (
-        vcol.dictionary if gather_like else None
+        vcol.host_dict if gather_like else None
     )
     return (
         JaxColumn(

@@ -145,6 +145,24 @@ def test_preagg_segment_aggs_compiles_on_four_chips(four_chips):
     assert "all-to-all" in compiled.as_text()
 
 
+def test_masked_segment_sums_compiles_on_four_chips(four_chips):
+    # TPC-H Q1's reduction on a row-sharded frame: 8 f64 payloads and a
+    # count over 12 bins, each chip's partial sums added across chips
+    mesh, rows = four_chips
+
+    def prog(seg, valid, *payloads):
+        f, c, _ = groupby.segment_sums(list(payloads), [valid], seg, 12, "masked")
+        return f, c
+
+    compiled = _compile(
+        prog,
+        _shape(ROWS, jnp.int32, rows),
+        _shape(ROWS, jnp.bool_, rows),
+        *[_shape(ROWS, jnp.float64, rows)] * 8,
+    )
+    assert "all-reduce" in compiled.as_text()
+
+
 def test_sharded_cumsum_compiles_on_four_chips(four_chips):
     mesh, rows = four_chips
     compiled = _compile(
