@@ -245,6 +245,50 @@ def row_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("p"))
 
 
+def place_rows(tree: Any, mesh: Mesh, reshards: Any = None) -> Any:
+    """A program's row-axis outputs (any pytree of arrays; ``None``
+    leaves pass through), each in the mesh's row sharding. An array
+    already so placed is returned as is: on a one-device mesh, and for
+    every row-wise result, this is only a check. A reduction's result
+    that XLA left replicated over the mesh is sliced on the devices, by
+    one cached identity program for the whole tree; the host never
+    touches it. Only an array on other devices than the mesh's goes
+    through the host (a blocking fetch, then one put per device),
+    counted on ``reshards`` (the engine's
+    ``fugue_engine_host_reshards_total``) when one is given.
+
+    Not done inside the producing program: constraining its outputs
+    there lets XLA's sharding propagation run backwards into the
+    reduction and repartition it."""
+    sharding = row_sharding(mesh)
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    todo = [
+        i for i, x in enumerate(leaves)
+        if x.ndim and not x.sharding.is_equivalent_to(sharding, x.ndim)
+    ]
+    if not todo:
+        return tree
+    devices = set(mesh.devices.flat)
+    local = [i for i in todo if leaves[i].sharding.device_set == devices]
+    if local:
+        avals = tuple((leaves[i].shape, leaves[i].dtype.str) for i in local)
+        placed = jit_row_sharded(mesh, ("place_rows", avals), _place_rows)(
+            *(leaves[i] for i in local)
+        )
+        for i, x in zip(local, placed):
+            leaves[i] = x
+    for i in todo:
+        if i not in local:
+            if reshards is not None:
+                reshards.inc()
+            leaves[i] = jax.device_put(leaves[i], sharding)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _place_rows(*xs: Any) -> Tuple[Any, ...]:
+    return xs
+
+
 # Compiled row-sharded programs are cached ON their mesh object (one
 # dict per mesh) instead of in a module-global keyed by the mesh: each
 # cached program's out_sharding holds a strong reference back to its
@@ -894,58 +938,47 @@ def replace_blocks(blocks: JaxBlocks, fresh: JaxBlocks) -> None:
     blocks.lost = False
 
 
-def gather_indices(blocks: JaxBlocks, idx: Any, schema: Schema) -> JaxBlocks:
+def gather_indices(
+    blocks: JaxBlocks, idx: Any, schema: Schema, reshards: Any = None
+) -> JaxBlocks:
     """Row-gather every device column in ONE jitted dispatch (host columns
-    via arrow take). ``idx`` must index real rows only."""
+    via arrow take). ``idx`` must index real rows only. The gather's
+    outputs reach their columns through ``place_rows`` (``reshards``
+    as there)."""
     mesh = blocks.mesh
-    ndev = mesh.devices.size
     new_n = int(idx.shape[0])
-    pad_n = padded_len(new_n, ndev)
-    sharding = row_sharding(mesh)
+    pad_n = padded_len(new_n, mesh.devices.size)
     device_cols = {n: c for n, c in blocks.columns.items() if c.on_device}
     datas = {n: c.data for n, c in device_cols.items()}
     masks = {n: c.mask for n, c in device_cols.items() if c.mask is not None}
     with on_mesh(mesh):
         idx_dev = jnp.asarray(idx)
-    out_d, out_m = _gather_program(pad_n)(datas, masks, idx_dev)
+    out_d, out_m = place_rows(
+        _gather(datas, masks, idx_dev, pad_n=pad_n), mesh, reshards
+    )
     cols: Dict[str, JaxColumn] = {}
     for name, col in blocks.columns.items():
         if not col.on_device:
             taken = col.data.take(pa.array(np.asarray(idx)))
             cols[name] = JaxColumn(col.pa_type, taken)
             continue
-        cols[name] = col.with_data(
-            jax.device_put(out_d[name], sharding),
-            None
-            if name not in out_m
-            else jax.device_put(out_m[name], sharding),
-        )
+        cols[name] = col.with_data(out_d[name], out_m.get(name))
     return JaxBlocks(new_n, cols, mesh)
 
 
-_GATHER_CACHE: Dict[int, Any] = {}
-
-
-def _gather_program(pad_n: int) -> Any:
-    """Jitted multi-column gather; padding rows repeat index 0 (garbage by
+@partial(jax.jit, static_argnames=("pad_n",))
+def _gather(
+    datas: Dict[str, jnp.ndarray],
+    masks: Dict[str, jnp.ndarray],
+    idx: jnp.ndarray,
+    pad_n: int,
+) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
+    """Multi-column gather; padding rows repeat index 0 (garbage by
     convention — consumers respect the frame's row membership)."""
-    if pad_n not in _GATHER_CACHE:
-
-        @jax.jit
-        def _gather(
-            datas: Dict[str, jnp.ndarray],
-            masks: Dict[str, jnp.ndarray],
-            idx: jnp.ndarray,
-        ) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
-            n = idx.shape[0]
-            if n != pad_n:
-                idx = jnp.concatenate(
-                    [idx, jnp.zeros((pad_n - n,), dtype=idx.dtype)]
-                )
-            return (
-                {k: v[idx] for k, v in datas.items()},
-                {k: v[idx] for k, v in masks.items()},
-            )
-
-        _GATHER_CACHE[pad_n] = _gather
-    return _GATHER_CACHE[pad_n]
+    n = idx.shape[0]
+    if n != pad_n:
+        idx = jnp.concatenate([idx, jnp.zeros((pad_n - n,), dtype=idx.dtype)])
+    return (
+        {k: v[idx] for k, v in datas.items()},
+        {k: v[idx] for k, v in masks.items()},
+    )

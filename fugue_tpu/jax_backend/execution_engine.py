@@ -76,6 +76,7 @@ from fugue_tpu.jax_backend.blocks import (
     gather_indices,
     make_mesh,
     padded_len,
+    place_rows,
     row_sharding,
 )
 from fugue_tpu.jax_backend.dataframe import JaxDataFrame
@@ -523,10 +524,13 @@ class JaxSQLEngine(PandasSQLEngine):
             (name, asc, None if nulls is None else (nulls == "FIRST"))
             for name, asc, nulls in plan.order_by
         ]
-        out = relational.device_sort(
-            engine, jdf.blocks, jdf.schema, sorts,
-            limit=plan.limit, offset=plan.offset,
-        )
+        with start_span(
+            "engine.sort", devices=int(jdf.blocks.mesh.devices.size)
+        ):
+            out = relational.device_sort(
+                engine, jdf.blocks, jdf.schema, sorts,
+                limit=plan.limit, offset=plan.offset,
+            )
         assert_or_throw(
             out is not None,
             ValueError("sort column not device-resident"),
@@ -699,6 +703,14 @@ class JaxExecutionEngine(ExecutionEngine):
             "trace budget (fugue.debug.retrace_sentinel.max_traces)",
             ["program"],
         )
+        # program outputs that blocks.place_rows had to reshard through
+        # the host (a blocking fetch, then a put per device): it slices
+        # outputs left replicated on the devices, so a standing zero
+        self._host_reshards = self.metrics.counter(
+            "fugue_engine_host_reshards_total",
+            "program outputs resharded through the host onto the mesh's "
+            "row sharding (outputs on other devices than the mesh's)",
+        ).labels()
         # compile/execute/disk-load wall clock split of every jitted
         # dispatch since construction — the daemon's time_to_first_query
         # phase report reads deltas of this
@@ -1461,8 +1473,8 @@ class JaxExecutionEngine(ExecutionEngine):
                  expr_eval.dict_fingerprint(blocks)),
                 _assign_prog,
             )(expr_eval.blocks_to_masked(blocks))
-            sharding = row_sharding(blocks.mesh)
             new_cols = dict(blocks.columns)
+            made: Dict[str, Tuple[Any, ...]] = {}
             for name, tp, c in plans:
                 # bare column references keep their dictionary/stats
                 # (same rule as _device_project)
@@ -1486,15 +1498,14 @@ class JaxExecutionEngine(ExecutionEngine):
                     data, dict_r, stats = expr_eval.finalize_string_result(
                         data, dict_r
                     )
-                new_cols[name] = JaxColumn(
-                    tp,
-                    jax.device_put(data, sharding),
-                    None
-                    if f"m:{name}" not in outs
-                    else jax.device_put(outs[f"m:{name}"], sharding),
-                    dict_r,
-                    stats,
-                )
+                made[name] = (tp, data, outs.get(f"m:{name}"), dict_r, stats)
+            placed = place_rows(
+                {n: (d, m) for n, (_, d, m, _, _) in made.items()},
+                blocks.mesh, self._host_reshards,
+            )
+            for name, (tp, _, _, dict_r, stats) in made.items():
+                data, mask = placed[name]
+                new_cols[name] = JaxColumn(tp, data, mask, dict_r, stats)
             return JaxDataFrame(blocks_with_columns(blocks, new_cols), schema)
         self._count_fallback("assign")
         return self.to_df(self._native.assign(jdf.as_local_bounded(), columns))
@@ -1571,7 +1582,10 @@ class JaxExecutionEngine(ExecutionEngine):
         from fugue_tpu.jax_backend.blocks import gather_indices
 
         return JaxDataFrame(
-            gather_indices(blocks, jnp.asarray(idx), jdf.schema), jdf.schema
+            gather_indices(
+                blocks, jnp.asarray(idx), jdf.schema, self._host_reshards
+            ),
+            jdf.schema,
         )
 
     def broadcast(self, df: DataFrame) -> DataFrame:
@@ -1995,7 +2009,10 @@ class JaxExecutionEngine(ExecutionEngine):
         count = n if n is not None else int(round(total * frac))  # type: ignore
         idx = valid_idx[rng.choice(total, size=count, replace=True)]
         return JaxDataFrame(
-            gather_indices(jdf.blocks, jnp.asarray(np.sort(idx)), jdf.schema),
+            gather_indices(
+                jdf.blocks, jnp.asarray(np.sort(idx)), jdf.schema,
+                self._host_reshards,
+            ),
             jdf.schema,
         )
 
@@ -2238,8 +2255,7 @@ class JaxExecutionEngine(ExecutionEngine):
              expr_eval.dict_fingerprint(blocks)),
             _project_prog,
         )(expr_eval.blocks_to_masked(blocks))
-        sharding = row_sharding(blocks.mesh)
-        new_cols: Dict[str, JaxColumn] = {}
+        made: Dict[str, Tuple[Any, ...]] = {}
         for c, f in zip(exprs, out_schema.fields):
             # plain column references keep their stats/dictionary
             src = (
@@ -2262,15 +2278,17 @@ class JaxExecutionEngine(ExecutionEngine):
                 data, dict_r, stats = expr_eval.finalize_string_result(
                     data, dict_r
                 )
-            new_cols[f.name] = JaxColumn(
-                f.type,
-                jax.device_put(data, sharding),
-                None
-                if f"m:{f.name}" not in outs
-                else jax.device_put(outs[f"m:{f.name}"], sharding),
-                dict_r,
-                stats,
+            made[f.name] = (
+                f.type, data, outs.get(f"m:{f.name}"), dict_r, stats
             )
+        placed = place_rows(
+            {n: (d, m) for n, (_, d, m, _, _) in made.items()},
+            blocks.mesh, self._host_reshards,
+        )
+        new_cols: Dict[str, JaxColumn] = {}
+        for name, (tp, _, _, dict_r, stats) in made.items():
+            data, mask = placed[name]
+            new_cols[name] = JaxColumn(tp, data, mask, dict_r, stats)
         return JaxDataFrame(
             blocks_with_columns(blocks, new_cols), out_schema
         )
@@ -2821,11 +2839,11 @@ class JaxExecutionEngine(ExecutionEngine):
             if tp is None:
                 return None
             typed_plans.append((name, func, arg, tp))
-        ndev = int(blocks.mesh.devices.size)
-        sharding = row_sharding(blocks.mesh)
+        mesh = blocks.mesh
+        ndev = int(mesh.devices.size)
         if len(keys) == 0:
             return self._global_aggregate(
-                jdf, typed_plans, col_order, sharding, distinct_args
+                jdf, typed_plans, col_order, distinct_args
             )
         bspec = groupby.bin_spec(blocks, keys)
         if bspec is not None:
@@ -2855,7 +2873,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 if strategy is not None:
                     return self._binned_packed_aggregate(
                         jdf, keys, typed_plans, bspec, col_order,
-                        sharding, strategy, distinct_args,
+                        strategy, distinct_args,
                     )
         fr = groupby.factorize_keys(blocks, keys)
         num_segments = fr.num_segments
@@ -3034,26 +3052,23 @@ class JaxExecutionEngine(ExecutionEngine):
             self._count_shuffle(
                 "aggregate", nbytes, time.perf_counter() - t0, use_overlap
             )
+        outs = place_rows(outs, mesh, self._host_reshards)
         out_cols: Dict[str, JaxColumn] = {}
         schema_fields = [jdf.schema[k] for k in keys]
         for k in keys:
             src_col = blocks.columns[k]
             out_cols[k] = JaxColumn(
                 src_col.pa_type,
-                jax.device_put(outs[f"k:{k}"], sharding),
-                None if f"km:{k}" not in outs else jax.device_put(
-                    outs[f"km:{k}"], sharding
-                ),
+                outs[f"k:{k}"],
+                outs.get(f"km:{k}"),
                 src_col.host_dict,
                 src_col.stats,
             )
         for name, func, arg, tp in typed_plans:
             out_cols[name] = JaxColumn(
                 tp,
-                jax.device_put(outs[f"a:{name}"], sharding),
-                None if f"am:{name}" not in outs else jax.device_put(
-                    outs[f"am:{name}"], sharding
-                ),
+                outs[f"a:{name}"],
+                outs.get(f"am:{name}"),
             )
             schema_fields.append(pa.field(name, tp))
         schema = Schema(schema_fields)
@@ -3063,13 +3078,12 @@ class JaxExecutionEngine(ExecutionEngine):
         if "_occupied" in outs:
             # binned path: empty bins masked out lazily; count stays a
             # device scalar until the host asks
-            row_valid_out = jax.device_put(outs["_occupied"], sharding)
             return JaxDataFrame(
                 JaxBlocks(
                     None,
                     out_cols,
                     blocks.mesh,
-                    row_valid=row_valid_out,
+                    row_valid=outs["_occupied"],
                     nrows_dev=fr.num_groups_dev,
                 ),
                 schema,
@@ -3320,14 +3334,16 @@ class JaxExecutionEngine(ExecutionEngine):
         jdf: JaxDataFrame,
         typed_plans: List[Tuple[str, str, Any, pa.DataType]],
         col_order: Optional[List[str]],
-        sharding: Any,
         distinct_args: Optional[Dict[str, str]] = None,
     ) -> DataFrame:
         """Keyless aggregation: plain masked jnp reductions — one program,
         no segments, no scatter. DISTINCT aggregates contribute only the
-        first row of each value (a per-value factorize mask)."""
+        first row of each value (a per-value factorize mask). The one
+        result row is padded to the mesh inside the program."""
         blocks = jdf.blocks
+        mesh = blocks.mesh
         pad_n = blocks.padded_nrows
+        out_pad = padded_len(1, int(mesh.devices.size))
         dicts = expr_eval.dicts_of(blocks)
         dsegs, dfirsts = _distinct_factorize(blocks, [], distinct_args)
 
@@ -3418,11 +3434,13 @@ class JaxExecutionEngine(ExecutionEngine):
                         if mask is None
                         else (mask[pick] & any_valid)
                     )
-                outs[f"a:{name}"] = _cast_agg_result(
-                    jnp.asarray(v)[None], tp
+                outs[f"a:{name}"] = _pad_to(
+                    _cast_agg_result(jnp.asarray(v)[None], tp), out_pad
                 )
                 if m is not None:
-                    outs[f"am:{name}"] = jnp.asarray(m)[None]
+                    outs[f"am:{name}"] = _pad_to(
+                        jnp.asarray(m)[None], out_pad
+                    )
             return outs
 
         prog_key = (
@@ -3434,6 +3452,7 @@ class JaxExecutionEngine(ExecutionEngine):
             pad_n,
             tuple(sorted((distinct_args or {}).items())),
             expr_eval.dict_fingerprint(blocks),
+            out_pad,
         )
         outs = self._jit_cached(prog_key, _prog)(
             expr_eval.blocks_to_masked(blocks),
@@ -3442,21 +3461,14 @@ class JaxExecutionEngine(ExecutionEngine):
             blocks.row_valid,
             _nrows_arg(blocks),
         )
-        ndev = int(blocks.mesh.devices.size)
-        out_pad = padded_len(1, ndev)
+        outs = place_rows(outs, mesh, self._host_reshards)
         out_cols: Dict[str, JaxColumn] = {}
         schema_fields = []
         for name, func, arg, tp in typed_plans:
             out_cols[name] = JaxColumn(
                 tp,
-                jax.device_put(
-                    _pad_to(outs[f"a:{name}"], out_pad), sharding
-                ),
-                None
-                if f"am:{name}" not in outs
-                else jax.device_put(
-                    _pad_to(outs[f"am:{name}"], out_pad), sharding
-                ),
+                outs[f"a:{name}"],
+                outs.get(f"am:{name}"),
             )
             schema_fields.append(pa.field(name, tp))
         schema = Schema(schema_fields)
@@ -3474,7 +3486,6 @@ class JaxExecutionEngine(ExecutionEngine):
         typed_plans: List[Tuple[str, str, Any, pa.DataType]],
         bspec: "groupby.BinSpec",
         col_order: Optional[List[str]],
-        sharding: Any,
         strategy: str,
         distinct_args: Optional[Dict[str, str]] = None,
     ) -> DataFrame:
@@ -3486,11 +3497,14 @@ class JaxExecutionEngine(ExecutionEngine):
         from bin indices (gather-free). Zero host syncs on the matmul and
         scatter strategies; the group count stays a lazy device scalar.
         DISTINCT aggregates fold their first-occurrence-of-(keys, value)
-        masks into the payloads, so they ride the same packed kernel."""
+        masks into the payloads, so they ride the same packed kernel.
+        On a multi-device mesh the cross-device sum leaves the bins
+        replicated; ``place_rows`` slices them on the devices."""
         blocks = jdf.blocks
+        mesh = blocks.mesh
         pad_n = blocks.padded_nrows
         dicts = expr_eval.dicts_of(blocks)
-        ndev = int(blocks.mesh.devices.size)
+        ndev = int(mesh.devices.size)
         total = bspec.total
         out_pad = padded_len(total, ndev)
         key_dtypes = {k: blocks.columns[k].data.dtype for k in keys}
@@ -3638,26 +3652,23 @@ class JaxExecutionEngine(ExecutionEngine):
             blocks.row_valid,
             _nrows_arg(blocks),
         )
+        outs = place_rows(outs, mesh, self._host_reshards)
         out_cols: Dict[str, JaxColumn] = {}
         schema_fields = [jdf.schema[k] for k in keys]
         for k in keys:
             src_col = blocks.columns[k]
             out_cols[k] = JaxColumn(
                 src_col.pa_type,
-                jax.device_put(outs[f"k:{k}"], sharding),
-                None
-                if f"km:{k}" not in outs
-                else jax.device_put(outs[f"km:{k}"], sharding),
+                outs[f"k:{k}"],
+                outs.get(f"km:{k}"),
                 src_col.host_dict,
                 src_col.stats,
             )
         for name, func, arg, tp in typed_plans:
             out_cols[name] = JaxColumn(
                 tp,
-                jax.device_put(outs[f"a:{name}"], sharding),
-                None
-                if f"am:{name}" not in outs
-                else jax.device_put(outs[f"am:{name}"], sharding),
+                outs[f"a:{name}"],
+                outs.get(f"am:{name}"),
             )
             schema_fields.append(pa.field(name, tp))
         schema = Schema(schema_fields)
@@ -3668,8 +3679,8 @@ class JaxExecutionEngine(ExecutionEngine):
             JaxBlocks(
                 None,
                 out_cols,
-                blocks.mesh,
-                row_valid=jax.device_put(outs["_occupied"], sharding),
+                mesh,
+                row_valid=outs["_occupied"],
                 nrows_dev=outs["_num"],
             ),
             schema,

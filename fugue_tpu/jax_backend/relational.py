@@ -37,7 +37,7 @@ from fugue_tpu.jax_backend.blocks import (
     jit_row_sharded,
     on_mesh,
     padded_len,
-    row_sharding,
+    place_rows,
 )
 from fugue_tpu.schema import Schema
 from fugue_tpu.utils.assertion import assert_or_throw
@@ -519,7 +519,6 @@ def expand_join(
     R = int(r_total) if how == "fullouter" else 0
     ndev = int(mesh.devices.size)
     out_pad = padded_len(M, ndev)
-    sharding = row_sharding(mesh)
 
     d1 = {n: b1.columns[n] for n in schema1.names}
     other2 = [n for n in schema2.names if n not in schema1.names]
@@ -609,6 +608,9 @@ def expand_join(
         cstart2,
         seg1,
     )
+    g1, gm1, g2, gm2 = place_rows(
+        (g1, gm1, g2, gm2), mesh, engine._host_reshards
+    )
     cols: Dict[str, JaxColumn] = {}
     for f in out_schema.fields:
         n = f.name
@@ -616,13 +618,7 @@ def expand_join(
             src, data, mask = d1[n], g1[n], gm1.get(n)
         else:
             src, data, mask = d2[n], g2[n], gm2.get(n)
-        cols[n] = JaxColumn(
-            f.type,
-            jax.device_put(data, sharding),
-            None if mask is None else jax.device_put(mask, sharding),
-            src.host_dict,
-            src.stats,
-        )
+        cols[n] = JaxColumn(f.type, data, mask, src.host_dict, src.stats)
     out = JaxBlocks(M, cols, mesh)
     if how == "fullouter" and R > 0:
         right_part = _gather_right_unmatched(
@@ -653,7 +649,6 @@ def _unique_right_join(
     and uniqueness intact), the row count stays lazy."""
     mesh = b1.mesh
     p1, p2 = b1.padded_nrows, b2.padded_nrows
-    sharding = row_sharding(mesh)
     other2 = [n for n in schema2.names if n not in schema1.names]
     d2 = {n: b2.columns[n] for n in other2}
     inner = how == "inner"
@@ -710,6 +705,7 @@ def _unique_right_join(
         {n: c.data for n, c in d2.items()},
         {n: c.mask for n, c in d2.items() if c.mask is not None},
     )
+    g2, gm2 = place_rows((g2, gm2), mesh, engine._host_reshards)
     cols: Dict[str, JaxColumn] = {}
     for f in out_schema.fields:
         n = f.name
@@ -717,8 +713,8 @@ def _unique_right_join(
             src = d2[n]
             cols[n] = JaxColumn(
                 f.type,
-                jax.device_put(g2[n], sharding),
-                jax.device_put(gm2[n], sharding),
+                g2[n],
+                gm2[n],
                 src.host_dict,
                 src.stats,
             )
@@ -749,7 +745,6 @@ def _gather_right_unmatched(
     mesh = b2.mesh
     ndev = int(mesh.devices.size)
     out_pad = padded_len(R, ndev)
-    sharding = row_sharding(mesh)
     src_cols: Dict[str, JaxColumn] = {}
     left_only: List[str] = []
     for f in out_schema.fields:
@@ -794,6 +789,7 @@ def _gather_right_unmatched(
         {n: c.mask for n, c in src_cols.items() if c.mask is not None},
         order_un2,
     )
+    g, gm = place_rows((g, gm), mesh, engine._host_reshards)
     cols: Dict[str, JaxColumn] = {}
     for f in out_schema.fields:
         n = f.name
@@ -801,20 +797,26 @@ def _gather_right_unmatched(
             src = src_cols[n]
             cols[n] = JaxColumn(
                 f.type,
-                jax.device_put(g[n], sharding),
-                None if n not in gm else jax.device_put(gm[n], sharding),
+                g[n],
+                gm.get(n),
                 src.host_dict,
                 src.stats,
             )
         else:
-            # left-only column: all NULL
-            dt = _null_device_dtype(f.type)
+            # left-only column: all NULL, made in the row sharding
+            dt = np.dtype(_null_device_dtype(f.type))
+            data, mask = jit_row_sharded(
+                mesh,
+                ("null_column", out_pad, dt.str),
+                lambda dt=dt: (
+                    jnp.zeros((out_pad,), dtype=dt),
+                    jnp.zeros((out_pad,), dtype=bool),
+                ),
+            )()
             cols[n] = JaxColumn(
                 f.type,
-                jax.device_put(jnp.zeros((out_pad,), dtype=dt), sharding),
-                jax.device_put(
-                    jnp.zeros((out_pad,), dtype=bool), sharding
-                ),
+                data,
+                mask,
                 np.asarray([], dtype=object) if _is_str(f.type) else None,
                 None,
             )
@@ -910,16 +912,14 @@ def repartition_by_key(
         time.perf_counter() - t0,
         False,
     )
-    sharding = row_sharding(mesh)
+    outs = place_rows(outs, mesh, engine._host_reshards)
     out_cols: Dict[str, JaxColumn] = {}
     for n in names:
         src = blocks.columns[n]
         out_cols[n] = JaxColumn(
             src.pa_type,
-            jax.device_put(outs[f"d:{n}"], sharding),
-            jax.device_put(outs[f"m:{n}"], sharding)
-            if n in mask_names
-            else None,
+            outs[f"d:{n}"],
+            outs.get(f"m:{n}"),
             src.host_dict,
             src.stats,
         )
@@ -927,7 +927,7 @@ def repartition_by_key(
         blocks._nrows,
         out_cols,
         mesh,
-        row_valid=jax.device_put(outs["_valid"], sharding),
+        row_valid=outs["_valid"],
         nrows_dev=blocks._nrows_dev,
     )
 
@@ -1210,13 +1210,13 @@ def device_fillna(
         },
         {nm: jnp.asarray(enc[nm]) for nm in names},
     )
-    sharding = row_sharding(blocks.mesh)
+    outs = place_rows(outs, blocks.mesh, engine._host_reshards)
     new_cols = dict(blocks.columns)
     for nm in names:
         src = blocks.columns[nm]
         new_cols[nm] = JaxColumn(
             src.pa_type,
-            jax.device_put(outs[nm], sharding),
+            outs[nm],
             None,
             src.host_dict,
             src.stats,
@@ -1450,7 +1450,9 @@ def device_sort(
     stop = n if limit is None else min(n, start + limit)
     from fugue_tpu.jax_backend.blocks import gather_indices
 
-    return gather_indices(blocks, order[start:stop], schema)
+    return gather_indices(
+        blocks, order[start:stop], schema, engine._host_reshards
+    )
 
 
 @_mesh_scoped(1)
@@ -1652,8 +1654,8 @@ def _window_rank_family(
         if kind in ("percent_rank", "cume_dist")
         else pa.int64()
     )
-    sharding = row_sharding(blocks.mesh)
-    return (JaxColumn(tp, jax.device_put(rn, sharding)), tp)
+    rn = place_rows(rn, blocks.mesh, engine._host_reshards)
+    return JaxColumn(tp, rn), tp
 
 
 def _window_frame_agg(
@@ -2076,19 +2078,11 @@ def _window_frame_agg(
         blocks.row_valid,
         _nrows_arg(blocks),
     )
-    sharding = row_sharding(blocks.mesh)
     dictionary = None if vcol is None else (
         vcol.host_dict if gather_like else None
     )
-    return (
-        JaxColumn(
-            tp,
-            jax.device_put(out, sharding),
-            None if outm is None else jax.device_put(outm, sharding),
-            dictionary=dictionary,
-        ),
-        tp,
-    )
+    out, outm = place_rows((out, outm), blocks.mesh, engine._host_reshards)
+    return JaxColumn(tp, out, outm, dictionary=dictionary), tp
 
 
 def _window_segment_agg(
@@ -2161,15 +2155,8 @@ def _window_segment_agg(
         ),
         _prog,
     )(values, vmask, seg, blocks.row_valid, _nrows_arg(blocks))
-    sharding = row_sharding(blocks.mesh)
-    return (
-        JaxColumn(
-            tp,
-            jax.device_put(out, sharding),
-            None if outm is None else jax.device_put(outm, sharding),
-        ),
-        tp,
-    )
+    out, outm = place_rows((out, outm), blocks.mesh, engine._host_reshards)
+    return JaxColumn(tp, out, outm), tp
 
 
 @_mesh_scoped(1)
