@@ -62,7 +62,7 @@ class ConfValueTypeRule(Rule):
         declared = declared_conf_keys()
         for key in sorted(ctx.conf.keys()):
             info = declared.get(key)
-            if info is None or info.type is object:
+            if info is None:
                 continue
             value = ctx.conf[key]
             try:

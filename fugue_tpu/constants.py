@@ -42,9 +42,6 @@ FUGUE_CONF_JAX_MIN_DEVICE_BYTES = "fugue.jax.placement.min_device_bytes"
 FUGUE_CONF_JAX_COMPILE_CACHE = "fugue.jax.compile.cache"
 FUGUE_CONF_JAX_IO_BATCH_ROWS = "fugue.jax.io.batch_rows"
 FUGUE_CONF_JAX_IO_PIPELINE = "fugue.jax.io.pipeline"
-FUGUE_CONF_JAX_GROUPBY_MATMUL = "fugue.jax.groupby.matmul"
-FUGUE_CONF_JAX_GROUPBY_STRATEGY = "fugue.jax.groupby.strategy"
-FUGUE_CONF_JAX_GROUPBY_AUTOTUNE = "fugue.jax.groupby.autotune"
 FUGUE_CONF_JAX_SHUFFLE = "fugue.jax.shuffle"
 FUGUE_CONF_JAX_SHUFFLE_OVERLAP = "fugue.jax.shuffle.overlap"
 FUGUE_CONF_JAX_DEVICES = "fugue.jax.devices"
@@ -146,10 +143,10 @@ FUGUE_COMPILE_TIME_CONFIGS = {
 }
 
 class ConfKeyInfo(NamedTuple):
-    """One declared conf key: its value type (``object`` = unchecked),
-    default, and description. ``in_defaults=False`` keys are declared (the
-    analyzer knows them) but deliberately NOT seeded into the global conf
-    (e.g. legacy/no-op knobs)."""
+    """One declared conf key: its value type, default, and description.
+    ``in_defaults=False`` keys are declared (the analyzer knows them) but
+    deliberately NOT seeded into the global conf (e.g. legacy/no-op
+    knobs)."""
 
     key: str
     type: type
@@ -190,15 +187,11 @@ def conf_default(key: str) -> Any:
 def typed_conf_get(conf: Any, key: str) -> Any:
     """Read a declared key from a conf mapping: missing keys return the
     registered default, present values coerce to the key's DECLARED type
-    (the same ``_convert`` semantics the analyzer's FWF202 rule checks;
-    ``object``-typed keys pass through untouched)."""
+    (the same ``_convert`` semantics the analyzer's FWF202 rule checks)."""
     info = _CONF_REGISTRY[key]
     if key not in conf:
         return info.default
-    value = conf[key]
-    if info.type is object:
-        return value
-    return _convert(value, info.type)
+    return _convert(conf[key], info.type)
 
 
 def _declare_defaults() -> None:
@@ -307,27 +300,6 @@ def _declare_defaults() -> None:
         "overlap streamed-IO decode/staging with executable warm (load) "
         "and row-group writes with result fetch (save)",
     )
-    # group-by reduction algorithm (legacy knob, kept for back-compat):
-    # "always"/"never" pin the strategy below to matmul/scatter; "auto"
-    # defers to fugue.jax.groupby.strategy.
-    r(FUGUE_CONF_JAX_GROUPBY_MATMUL, str, "auto", "legacy matmul pin: auto | always | never")
-    # segment-reduction strategy: "auto" consults the measured crossover
-    # table in jax_backend/segtune.py (scatter on CPU meshes; on
-    # accelerators masked VPU sums for f64 payloads over few bins, one-hot
-    # matmul below the segment cap, sorted scatter above it), sharpened
-    # by a one-shot on-device autotune; or pin one of "matmul" |
-    # "matmul_bf16" | "scatter" | "sort" | "masked". matmul_bf16 trades
-    # ~8 mantissa bits for speed and is PIN-ONLY — auto never picks it.
-    r(
-        FUGUE_CONF_JAX_GROUPBY_STRATEGY,
-        str,
-        "auto",
-        "segment-reduction kernel: "
-        "auto | matmul | matmul_bf16 | scatter | sort | masked",
-    )
-    # autotune policy: "auto" probes on accelerator meshes for large
-    # frames only; True/False force it on/off. Mixed-type by design.
-    r(FUGUE_CONF_JAX_GROUPBY_AUTOTUNE, object, "auto", "one-shot strategy autotune: auto | bool")
     # all-to-all shuffle repartition (jax_backend/shuffle.py): co-locate
     # matching keys per device shard before segment reductions (group-by,
     # join match counts). "auto" shuffles only on multi-device meshes for

@@ -65,7 +65,7 @@ from fugue_tpu.execution.native_execution_engine import (
     PandasMapEngine,
     PandasSQLEngine,
 )
-from fugue_tpu.jax_backend import expr_eval, groupby, relational
+from fugue_tpu.jax_backend import expr_eval, groupby, relational, shuffle
 from fugue_tpu.jax_backend.blocks import (
     JaxBlocks,
     JaxColumn,
@@ -2841,6 +2841,7 @@ class JaxExecutionEngine(ExecutionEngine):
             typed_plans.append((name, func, arg, tp))
         mesh = blocks.mesh
         ndev = int(mesh.devices.size)
+        platform = mesh.devices.flat[0].platform
         if len(keys) == 0:
             return self._global_aggregate(
                 jdf, typed_plans, col_order, distinct_args
@@ -2859,16 +2860,8 @@ class JaxExecutionEngine(ExecutionEngine):
                     if atp is None or not pa.types.is_float32(atp):
                         float_dtype = np.float64
             if all(k is not None for k in kinds):
-                # payload estimate for the crossover table: every plan
-                # contributes at most one payload row + the occupancy slot
-                # (dedup inside the program can only shrink it)
-                strategy = self._groupby_strategy(
-                    blocks,
-                    pad_n,
-                    bspec.total,
-                    1 + len(typed_plans),
-                    need_int=need_int,
-                    float_dtype=float_dtype,
+                strategy = groupby.choose_strategy(
+                    platform, bspec.total, float_dtype, need_int
                 )
                 if strategy is not None:
                     return self._binned_packed_aggregate(
@@ -2881,28 +2874,27 @@ class JaxExecutionEngine(ExecutionEngine):
         # the generic (unpacked) path still routes its sum-type reductions
         # through the strategy layer per tier — min/max/median etc. stay
         # scatter-native inside _segment_agg_impl
-        seg_strategy = self._count_reduce_strategy(blocks, num_segments)
+        seg_strategy = groupby.choose_strategy(
+            platform, num_segments, count_only=True
+        )
         # devices-aware column of the strategy decision: on multi-device
         # meshes, repartition rows by key (all-to-all) so each device
         # reduces only its own segments instead of every device reducing
         # the full segment space redundantly
-        from fugue_tpu.jax_backend import segtune as _segtune
-        from fugue_tpu.jax_backend import shuffle as _shuffle
-
-        use_shuffle = _segtune.choose_shuffle(
+        use_shuffle = shuffle.choose_shuffle(
             self._shuffle_mode(), blocks.mesh, pad_n, num_segments
         )
         # combinable plan sets ride the map-side combine (partial
         # aggregation + reduce-scatter-layout all-to-all): O(S * ndev)
         # traffic. Only non-combinable aggregates (median, variance)
         # need the O(rows * ndev) row shuffle
-        use_preagg = use_shuffle and _shuffle.preagg_ok(
+        use_preagg = use_shuffle and shuffle.preagg_ok(
             [f for _, f, _, _ in typed_plans]
         )
         use_overlap = (
             use_shuffle
             and not use_preagg
-            and _segtune.choose_overlap(
+            and shuffle.choose_overlap(
                 self._shuffle_overlap_mode(), blocks.mesh, num_segments
             )
         )
@@ -2945,7 +2937,7 @@ class JaxExecutionEngine(ExecutionEngine):
             if use_preagg:
                 # map-side combine: per-device partials, one tiny
                 # all-to-all of (ndev, S_local) partial tables
-                pairs = _shuffle.preagg_segment_aggs(
+                pairs = shuffle.preagg_segment_aggs(
                     mesh,
                     [f for _, f, _, _, _ in plan_inputs],
                     seg_,
@@ -2963,7 +2955,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 # count transports only its mask (values are unused by
                 # the count kernel — but the mask MUST travel, it folds
                 # into the effective row count)
-                pairs = _shuffle.shuffled_segment_aggs(
+                pairs = shuffle.shuffled_segment_aggs(
                     mesh,
                     [f for _, f, _, _, _ in plan_inputs],
                     seg_,
@@ -3038,7 +3030,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 widths = sum(
                     4 if f == "count" else 9 for _, f, _, _ in typed_plans
                 )
-                nbytes = _shuffle.estimate_preagg_bytes(
+                nbytes = shuffle.estimate_preagg_bytes(
                     num_segments, ndev, widths
                 )
             else:
@@ -3046,7 +3038,7 @@ class JaxExecutionEngine(ExecutionEngine):
                     (0 if f == "count" else 8) + 1
                     for _, f, _, _ in typed_plans
                 )
-                nbytes = _shuffle.estimate_shuffle_bytes(
+                nbytes = shuffle.estimate_shuffle_bytes(
                     pad_n, ndev, widths
                 )
             self._count_shuffle(
@@ -3161,39 +3153,12 @@ class JaxExecutionEngine(ExecutionEngine):
                 )
             )
 
-    def _strategy_mode(self) -> str:
-        """The configured strategy: ``fugue.jax.groupby.strategy``, with
-        the legacy ``fugue.jax.groupby.matmul`` knob mapped onto it
-        (always -> matmul, never -> scatter) for back-compat."""
-        from fugue_tpu.constants import (
-            FUGUE_CONF_JAX_GROUPBY_MATMUL,
-            FUGUE_CONF_JAX_GROUPBY_STRATEGY,
-        )
-
-        mode = str(
-            self.conf.get(FUGUE_CONF_JAX_GROUPBY_STRATEGY, "auto")
-        ).lower()
-        assert_or_throw(
-            mode == "auto" or mode in groupby.STRATEGIES,
-            ValueError(
-                f"{FUGUE_CONF_JAX_GROUPBY_STRATEGY}={mode!r} is not one of "
-                f"{('auto',) + groupby.STRATEGIES}"
-            ),
-        )
-        legacy = str(
-            self.conf.get(FUGUE_CONF_JAX_GROUPBY_MATMUL, "auto")
-        ).lower()
-        if mode == "auto" and legacy != "auto":
-            mode = "matmul" if legacy == "always" else "scatter"
-        return mode
-
     def _shuffle_mode(self) -> str:
         """``fugue.jax.shuffle`` normalized to auto/on/off — whether
         segment reductions repartition rows by key over the mesh first."""
         from fugue_tpu.constants import FUGUE_CONF_JAX_SHUFFLE
-        from fugue_tpu.jax_backend import segtune
 
-        return segtune.shuffle_mode(
+        return shuffle.shuffle_mode(
             self.conf.get(FUGUE_CONF_JAX_SHUFFLE, "auto"),
             FUGUE_CONF_JAX_SHUFFLE,
         )
@@ -3203,9 +3168,8 @@ class JaxExecutionEngine(ExecutionEngine):
         whether shuffled reductions double-buffer the next key-range's
         all-to-all behind the current range's local reduction."""
         from fugue_tpu.constants import FUGUE_CONF_JAX_SHUFFLE_OVERLAP
-        from fugue_tpu.jax_backend import segtune
 
-        return segtune.shuffle_mode(
+        return shuffle.shuffle_mode(
             self.conf.get(FUGUE_CONF_JAX_SHUFFLE_OVERLAP, "auto"),
             FUGUE_CONF_JAX_SHUFFLE_OVERLAP,
         )
@@ -3214,97 +3178,9 @@ class JaxExecutionEngine(ExecutionEngine):
         """Shuffle decision for relational.py's join count reductions —
         same strategy column as aggregates, exposed so expand_join does
         not reach into conf itself."""
-        from fugue_tpu.jax_backend import segtune
-
-        return segtune.choose_shuffle(
+        return shuffle.choose_shuffle(
             self._shuffle_mode(), mesh, rows, num_segments
         )
-
-    def _groupby_strategy(
-        self,
-        blocks: JaxBlocks,
-        rows: int,
-        num_segments: int,
-        n_payload: int,
-        need_int: bool = False,
-        float_dtype: Any = np.float32,
-    ) -> Optional[str]:
-        """Select the packed segment-reduction strategy for one aggregate
-        shape, or None when no strategy is eligible (the caller then takes
-        the generic per-agg path). ``float_dtype`` is the float payloads'
-        accumulator dtype (f32 when every float payload is f32 or there is
-        none, else f64). Eligibility: the matmul family and masked cannot
-        sum integers exactly; matmul is capped at _MATMUL_MAX_SEGMENTS
-        (the one-hot transient), matmul_bf16 additionally needs f32 float
-        payloads, masked runs up to _MASKED_MAX_SEGMENTS bins;
-        scatter/sort run up to _PACKED_MAX_SEGMENTS. ``auto`` consults
-        segtune's measured table + one-shot on-device autotune and offers
-        masked only for f64 payloads; an explicit conf pin is honored
-        when eligible."""
-        from fugue_tpu.constants import FUGUE_CONF_JAX_GROUPBY_AUTOTUNE
-        from fugue_tpu.jax_backend import segtune
-
-        f64 = np.dtype(float_dtype) == np.float64
-        candidates: List[str] = []
-        if not need_int and num_segments <= groupby._MATMUL_MAX_SEGMENTS:
-            candidates.append("matmul")
-            if not f64:
-                candidates.append("matmul_bf16")
-        if not need_int and num_segments <= groupby._MASKED_MAX_SEGMENTS:
-            candidates.append("masked")
-        if num_segments <= groupby._PACKED_MAX_SEGMENTS:
-            candidates.extend(["scatter", "sort"])
-        if not candidates:
-            return None
-        mode = self._strategy_mode()
-        if mode != "auto":
-            return mode if mode in candidates else None
-        # bf16's hi/lo split trades ~8 mantissa bits for speed — an
-        # accuracy change users must PIN into, never an autotune pick
-        # (review finding). masked is auto's kernel for f64 only: f32
-        # keeps the MXU matmul its measured table picks
-        candidates = [
-            c
-            for c in candidates
-            if c != "matmul_bf16" and (f64 or c != "masked")
-        ]
-        return segtune.choose_strategy(
-            blocks.mesh,
-            rows,
-            num_segments,
-            n_payload,
-            candidates,
-            typed_conf_get(self.conf, FUGUE_CONF_JAX_GROUPBY_AUTOTUNE),
-            self.log,
-            float_dtype=float_dtype,
-        )
-
-    def _count_reduce_strategy(
-        self, blocks: JaxBlocks, num_segments: int
-    ) -> str:
-        """Strategy for single-payload 0/1 count reductions (join sides,
-        window/generic aggregates): the shapes relational.py shares with
-        the group-by machinery. Sorting inside a join program is never
-        worth it for one payload, so the choice is matmul-vs-scatter by
-        tier and segment cap; explicit strategy pins map onto that pair."""
-        from fugue_tpu.jax_backend import segtune
-
-        mode = self._strategy_mode()
-        if mode in ("matmul", "matmul_bf16"):
-            return (
-                mode
-                if num_segments <= groupby._MATMUL_MAX_SEGMENTS
-                else "scatter"
-            )
-        if mode in ("scatter", "sort"):
-            return "scatter"
-        platform = blocks.mesh.devices.flat[0].platform
-        if (
-            platform != "cpu"
-            and num_segments <= groupby._MATMUL_MAX_SEGMENTS
-        ):
-            return "matmul"
-        return "scatter"
 
     def _packed_agg_kind(
         self, jdf: JaxDataFrame, func: str, arg: Any
@@ -3492,9 +3368,9 @@ class JaxExecutionEngine(ExecutionEngine):
         """The group-by hot path: ONE jitted program computing mixed-radix
         segment ids inline, ALL sum/avg/count reductions (float, exact-int
         and DISTINCT variants) packed into a single strategy kernel —
-        one-hot matmul / bf16 matmul / packed scatter / sorted scatter,
-        per the crossover selector — and key values decoded arithmetically
-        from bin indices (gather-free). Zero host syncs on the matmul and
+        one-hot matmul / masked sums / packed scatter / sorted scatter,
+        per ``groupby.choose_strategy`` — and key values decoded
+        arithmetically from bin indices (gather-free). Zero host syncs on the matmul and
         scatter strategies; the group count stays a lazy device scalar.
         DISTINCT aggregates fold their first-occurrence-of-(keys, value)
         masks into the payloads, so they ride the same packed kernel.

@@ -167,13 +167,13 @@ def decode_bin_keys(
 # All sum-type reductions (sum/avg/count payloads for every aggregated
 # column) are packed into one multi-row operand so the per-row segment
 # work — one-hot materialization, scatter index handling, or the sort —
-# is amortized across every output. Five interchangeable strategies
-# compute the identical contract; the engine picks one per (rows,
-# num_segments, n_payload, float dtype, placement tier) via a measured
-# table + a one-shot on-device autotune (see segtune.py):
+# is amortized across every output. Four interchangeable strategies
+# compute the identical contract; ``choose_strategy`` picks one from the
+# shape alone (placement tier, segment count, payload dtype) by the table
+# at the end of this block.
 #
 # - "matmul": chunked one-hot matmul over the MXU. The fastest measured
-#   on TPU for small segment counts. Benchmarked at 100M rows x 1024
+#   on TPU for f32 up to its segment cap. Benchmarked at 100M rows x 1024
 #   segments, f32, honest device_get endpoint (r3):
 #     one-hot matmul (this design)            ~204ms  (~490M rows/s)
 #     hierarchical (OH_hi*v)^T @ OH_lo split  ~490ms  (2.4x worse: two
@@ -186,37 +186,46 @@ def decode_bin_keys(
 #   pallas kernel was evaluated and offers no algorithmic advantage here
 #   (VPU compare-accumulate is the same n*S work at lower throughput in
 #   f32; in f64 it is the other way round, see "masked").
-# - "matmul_bf16": the same one-hot matmul with the one-hot in bf16 and
-#   each f32 payload split into hi+lo bf16 halves (two exact-0/1-weighted
-#   products, f32 MXU accumulation) — halves the one-hot transient
-#   traffic and rides the MXU's native bf16 rate at ~16 effective
-#   mantissa bits. Only eligible when every float payload is f32.
 # - "scatter": ONE packed (rows, n_payload) jax.ops.segment_sum. On CPU
 #   meshes (the host placement tier) the one-hot transient is pure
 #   memory-bandwidth waste while scatter-adds are cheap — measured
-#   10M rows x 256 segments = 1.28s matmul vs 0.048s scatter — so the
-#   table routes CPU meshes here. Exact integer accumulation (the matmul
-#   family would lose low bits in its float accumulator).
+#   10M rows x 256 segments = 1.28s matmul vs 0.048s scatter — so CPU
+#   meshes always scatter. Exact integer accumulation (matmul would lose
+#   low bits in its float accumulator).
 # - "sort": argsort by segment id, then the packed scatter with
-#   ``indices_are_sorted=True`` — XLA lowers sorted scatters to a far
-#   cheaper kernel, trading the n*S one-hot work for an n*log(n) sort.
-#   The crossover candidate for LARGE segment counts where the one-hot
-#   work dominates.
+#   ``indices_are_sorted=True``, trading the n*S one-hot work for an
+#   n*log(n) sort. It lost to scatter at every shape measured below.
 # - "masked": one masked select-and-sum per (segment, payload) on the
 #   VPU, each payload's bins fused into one pass over its column — no
-#   one-hot reaches HBM and no matmul runs. The f64 kernel: the MXU has no float64, so XLA
-#   emulates an f64 one-hot dot_general, and the (rows, S) one-hot pads
-#   S out to a full lane tile. Measured on one v5e at 10M rows x 1,024
-#   segments, f64 (PR 21): matmul 5.265s, scatter 0.956s, sort 1.398s
-#   (matmul in f32: 0.0117s). On one v5e, TPC-H Q1 at SF10 (60M rows,
-#   12 bins, 6 payloads, f64, PR 24): the whole Q1 program 58ms against
-#   3.15s on the f64 matmul; the autotune probe at 1M rows x 12 bins x 9
-#   payloads, f64: masked 2.4-5.7ms, matmul 67-70ms, scatter 106-110ms,
-#   sort 264-268ms. Its work is rows * S * P, so it is capped at
-#   _MASKED_MAX_SEGMENTS; f64 shapes past the cap are unmeasured.
+#   one-hot reaches HBM and no matmul runs. The f64 kernel: the MXU has
+#   no float64, so XLA emulates an f64 one-hot dot_general, and the
+#   (rows, S) one-hot pads S out to a full lane tile. On one v5e, TPC-H
+#   Q1 at SF10 (60M rows, 12 bins, 6 payloads, f64): the whole Q1
+#   program 58ms against 3.15s on the f64 matmul. Its work is
+#   rows * S * P, so it is capped at _MASKED_MAX_SEGMENTS.
+#
+# The table, on accelerator meshes. One v5e, 1M rows, 8 payloads of the
+# dtype and one count, each kernel compiled, warmed, then best of 2 (ms;
+# PERF.md §6 names the run; "int" is the exact-integer case, int64
+# payloads):
+#   segs  f32: matmul scatter   sort  f64: masked  matmul scatter   sort
+#   12           1.53   18.60 223.76          2.58   66.56  105.63 263.65
+#   64           1.70   18.70 164.56          4.64   77.11  107.93 265.78
+#   65           1.51   18.70 164.35             -   77.27  106.63 264.22
+#   1024         3.68   18.69 145.02             -  558.98  108.37 266.16
+#   8192         8.87   16.40 145.39             - 4342.39  106.43 266.18
+#   8193            -   16.56 145.29             -       -  106.44 264.65
+#   int: scatter 90.61 / sort 248.84 at 12, 91.45 / 248.64 at 1024,
+#   88.95 / 248.81 at 8193.
+# So: f32 matmul up to its cap, then scatter; f64 masked up to its cap,
+# the emulated matmul while the segments fit one 128-lane tile (65 bins,
+# 1.4x faster than scatter; 1,024 bins, eight tiles, 5.2x slower), then
+# scatter; exact ints scatter. An earlier v5e run agrees at 10M rows x
+# 1,024 segments, f64: matmul 5.265s, scatter 0.956s, sort 1.398s. No two
+# kernels came within 10% of each other.
 # ---------------------------------------------------------------------------
 
-STRATEGIES = ("matmul", "matmul_bf16", "scatter", "sort", "masked")
+STRATEGIES = ("matmul", "scatter", "sort", "masked")
 
 _MATMUL_MAX_SEGMENTS = 8192
 # masked work grows as rows * num_segments * n_payload: past a few dozen
@@ -227,9 +236,60 @@ _MASKED_MAX_SEGMENTS = 64
 _PACKED_MAX_SEGMENTS = 1 << 20
 _MATMUL_CHUNK = 1 << 17
 # cap on chunk*num_segments: the (chunk, num_segments) one-hot is the
-# scan-step transient; 2^26 elements = 256MB f32 (1/2 that in bf16), safe
-# on 16GB parts even if XLA fails to fuse it into the matmul (advisor r2)
+# scan-step transient; 2^26 elements = 256MB f32, safe on 16GB parts even
+# if XLA fails to fuse it into the matmul (advisor r2)
 _MATMUL_ONEHOT_BUDGET = 1 << 26
+
+# The MXU has no float64: XLA emulates an f64 one-hot dot with the segments
+# padded to 128-lane tiles, so its cost grows by whole tiles. One tile
+# beats the packed scatter, eight lose five-fold (table above)
+_MATMUL_F64_MAX_SEGMENTS = 128
+
+
+def eligible_strategies(
+    num_segments: int, need_int: bool = False, count_only: bool = False
+) -> Tuple[str, ...]:
+    """The kernels that can run one reduction shape. Exact integer sums
+    rule out the float-accumulating matmul and masked; matmul is capped by
+    its one-hot transient, masked by its rows * segments work, scatter and
+    sort by the packed output. ``count_only`` is the single-payload shape
+    of join-side counts and the generic aggregate path: matmul where its
+    cap allows, else scatter, which has no cap there."""
+    out: List[str] = []
+    if not need_int and num_segments <= _MATMUL_MAX_SEGMENTS:
+        out.append("matmul")
+    if count_only:
+        return tuple(out) + ("scatter",)
+    if not need_int and num_segments <= _MASKED_MAX_SEGMENTS:
+        out.append("masked")
+    if num_segments <= _PACKED_MAX_SEGMENTS:
+        out += ["scatter", "sort"]
+    return tuple(out)
+
+
+def choose_strategy(
+    platform: str,
+    num_segments: int,
+    float_dtype: Any = np.float32,
+    need_int: bool = False,
+    count_only: bool = False,
+) -> Optional[str]:
+    """The segment-reduction kernel for one shape on a mesh of ``platform``
+    devices, by the table above, or None when no kernel is eligible (the
+    caller then takes the generic per-aggregate path). ``float_dtype`` is
+    the float payloads' accumulator dtype; ``need_int`` says exact integer
+    sums are among the payloads. The sort kernel won no measured shape, so
+    the rule never picks it."""
+    eligible = eligible_strategies(num_segments, need_int, count_only)
+    if platform == "cpu" or need_int:
+        order: Tuple[str, ...] = ("scatter",)
+    elif np.dtype(float_dtype) != np.float64:
+        order = ("matmul", "scatter")
+    elif num_segments <= _MATMUL_F64_MAX_SEGMENTS:
+        order = ("masked", "matmul", "scatter")
+    else:
+        order = ("scatter",)
+    return next((s for s in order if s in eligible), None)
 
 
 def segment_sums(
@@ -245,7 +305,7 @@ def segment_sums(
     ``float_payloads`` accumulate in the widest float dtype present;
     ``count_payloads`` (bool/0-1 valued) accumulate exactly in int32;
     ``int_payloads`` accumulate exactly in int64 (scatter/sort only — the
-    matmul family's float accumulator would drop low bits, callers gate).
+    float accumulators of matmul and masked would drop low bits).
     ``seg`` values >= num_segments contribute nothing on every strategy.
     Returns (float_sums, count_sums, int_sums) as per-payload lists."""
     ints = int_payloads or []
@@ -262,17 +322,13 @@ def segment_sums(
             float_payloads, count_payloads, seg, num_segments
         )
         return f, c, []
-    if strategy in ("matmul", "matmul_bf16"):
+    if strategy == "matmul":
         assert_or_throw(
             len(ints) == 0,
-            ValueError("matmul strategies cannot sum integer payloads"),
+            ValueError("the matmul strategy cannot sum integer payloads"),
         )
         f, c = matmul_segment_sums(
-            float_payloads,
-            count_payloads,
-            seg,
-            num_segments,
-            bf16=strategy == "matmul_bf16",
+            float_payloads, count_payloads, seg, num_segments
         )
         return f, c, []
     return _packed_scatter_sums(
@@ -394,17 +450,12 @@ def matmul_segment_sums(
     count_payloads: List[jnp.ndarray],
     seg: jnp.ndarray,
     num_segments: int,
-    bf16: bool = False,
 ) -> Tuple[List[jnp.ndarray], List[jnp.ndarray]]:
     """Traced helper: all sum-type reductions in ONE chunked one-hot matmul
     over the MXU. ``float_payloads`` accumulate in f32/f64; ``count_payloads``
     (bool/0-1 valued) accumulate exactly in int32 (f32 partials per chunk
     are exact below the chunk size). ``seg`` values >= num_segments
-    contribute nothing (their one-hot row is all zeros).
-
-    ``bf16``: one-hot and payloads in bf16 with f32 MXU accumulation; each
-    f32 payload is split hi+lo so ~16 mantissa bits survive. Callers must
-    guarantee every float payload is f32 (gated by the strategy selector)."""
+    contribute nothing (their one-hot row is all zeros)."""
     n = seg.shape[0]
     ch = min(
         _MATMUL_CHUNK,
@@ -414,25 +465,7 @@ def matmul_segment_sums(
     pad = (-n) % ch
     acc_dtype = _float_acc_dtype(float_payloads)
     nf = len(float_payloads)
-    if bf16:
-        # split each f32 payload into exact-sum bf16 halves: hi = bf16(v),
-        # lo = bf16(v - hi); one-hot weights (0/1) are bf16-exact, so the
-        # two f32-accumulated products recover ~16 mantissa bits
-        op_dtype: Any = jnp.bfloat16
-        acc_dtype = jnp.float32
-        his = [p.astype(jnp.bfloat16) for p in float_payloads]
-        los = [
-            (p.astype(jnp.float32) - h.astype(jnp.float32)).astype(
-                jnp.bfloat16
-            )
-            for p, h in zip(float_payloads, his)
-        ]
-        payloads = his + los + [p.astype(jnp.bfloat16) for p in count_payloads]
-    else:
-        op_dtype = acc_dtype
-        payloads = [p.astype(acc_dtype) for p in float_payloads] + [
-            p.astype(acc_dtype) for p in count_payloads
-        ]
+    payloads = [p.astype(acc_dtype) for p in float_payloads + count_payloads]
     if pad:
         seg = jnp.concatenate(
             [seg, jnp.full((pad,), num_segments, dtype=seg.dtype)]
@@ -442,7 +475,6 @@ def matmul_segment_sums(
             for p in payloads
         ]
     a = len(payloads)
-    nsplit = 2 * nf if bf16 else nf
     kc = seg.reshape(-1, ch)
     pc = jnp.stack(payloads, axis=0).reshape(a, -1, ch)
     iota = jnp.arange(num_segments, dtype=seg.dtype)
@@ -450,21 +482,18 @@ def matmul_segment_sums(
     def body(carry: Tuple[Any, Any], kv: Any) -> Tuple[Tuple[Any, Any], None]:
         f_acc, c_acc = carry
         kk, vv = kv
-        oh = (kk[:, None] == iota[None, :]).astype(op_dtype)
+        oh = (kk[:, None] == iota[None, :]).astype(acc_dtype)
         part = jax.lax.dot_general(
             vv, oh, (((1,), (0,)), ((), ())),
             preferred_element_type=acc_dtype,
         )  # (a, num_segments), accumulated in acc_dtype
-        if bf16:
-            f_acc = f_acc + part[:nf] + part[nf:nsplit]
-        else:
-            f_acc = f_acc + part[:nf]
-        c_acc = c_acc + part[nsplit:].astype(jnp.int32)
+        f_acc = f_acc + part[:nf]
+        c_acc = c_acc + part[nf:].astype(jnp.int32)
         return (f_acc, c_acc), None
 
     init = (
         jnp.zeros((nf, num_segments), acc_dtype),
-        jnp.zeros((a - nsplit, num_segments), jnp.int32),
+        jnp.zeros((a - nf, num_segments), jnp.int32),
     )
     (f_acc, c_acc), _ = jax.lax.scan(
         body, init, (kc, jnp.moveaxis(pc, 0, 1))
@@ -688,10 +717,8 @@ def _segment_agg_impl(
     if f == "sum" or f in ("avg", "mean"):
         filled = jnp.where(effective, values, 0)
         use = strategy
-        if use == "matmul_bf16" and filled.dtype != jnp.float32:
-            use = "matmul"  # the hi/lo split assumes f32 payloads
         if not jnp.issubdtype(filled.dtype, jnp.floating):
-            if use in ("matmul", "matmul_bf16"):
+            if use == "matmul":
                 use = "scatter"  # exact int sums can't ride a float acc
             _, cs, is_ = segment_sums(
                 [], [effective], seg, num_segments, use,

@@ -281,7 +281,9 @@ def semi_anti_join(
     null2 = _null_any_mask(b2, keys)
     p1 = b1.padded_nrows
     # join-side count reductions share the group-by strategy layer
-    strat = engine._count_reduce_strategy(b1, S)
+    strat = groupby.choose_strategy(
+        b1.mesh.devices.flat[0].platform, S, count_only=True
+    )
 
     def _prog(
         seg1: Any,
@@ -338,7 +340,9 @@ def not_in_join(
     null1 = _null_any_mask(b1, keys)
     null2 = _null_any_mask(b2, keys)
     p1 = b1.padded_nrows
-    strat = engine._count_reduce_strategy(b1, S)
+    strat = groupby.choose_strategy(
+        b1.mesh.devices.flat[0].platform, S, count_only=True
+    )
 
     def _prog(
         seg1: Any,
@@ -437,7 +441,9 @@ def expand_join(
     # runs them as a map-side combine: each device counts its own rows
     # and one reduce-scatter-layout all-to-all of partial counts gives
     # every device its own segment range
-    strat = engine._count_reduce_strategy(b1, S)
+    strat = groupby.choose_strategy(
+        b1.mesh.devices.flat[0].platform, S, count_only=True
+    )
     shuf = not is_cross and engine._join_shuffle(mesh, max(p1, p2), S)
 
     def _count_prog(
@@ -1046,7 +1052,9 @@ def intersect_subtract(
     p1 = b1.padded_nrows
     # S + 1: the multiset branch reduces over the sentinel bucket too —
     # the selector must see the LARGEST segment count the program uses
-    strat = engine._count_reduce_strategy(b1, S + 1)
+    strat = groupby.choose_strategy(
+        b1.mesh.devices.flat[0].platform, S + 1, count_only=True
+    )
 
     def _prog(
         seg1: Any,
@@ -2127,7 +2135,9 @@ def _window_segment_agg(
 
     # windowed sum/avg/count are segment reductions too: same strategy
     # layer as the group-by (min/max stay scatter-native inside the impl)
-    strat = engine._count_reduce_strategy(blocks, S + 1)
+    strat = groupby.choose_strategy(
+        blocks.mesh.devices.flat[0].platform, S + 1, count_only=True
+    )
 
     def _prog(
         values_: Any,

@@ -41,8 +41,8 @@ The price of shape stability is a padded receive: every device
 receives ``ndev`` chunks of ``L`` rows, an ``ndev``-fold row blowup
 carried only through the (streaming, mask-aware) local reduction.
 That is the standard padded-all-to-all tradeoff; the decision of WHEN
-it pays lives in segtune.choose_shuffle (the devices-aware strategy
-column), not here.
+it pays lives in :func:`choose_shuffle` (the devices-aware strategy
+column).
 
 For COMBINABLE aggregates (count/sum/avg/min/max/first/last) the row
 shuffle is overkill: :func:`preagg_segment_aggs` is the map-side
@@ -71,6 +71,8 @@ from fugue_tpu.jax_backend import groupby
 
 __all__ = [
     "PREAGG_FUNCS",
+    "choose_overlap",
+    "choose_shuffle",
     "estimate_preagg_bytes",
     "estimate_shuffle_bytes",
     "grouped_sort",
@@ -84,7 +86,66 @@ __all__ = [
     "shuffle_rows",
     "shuffled_segment_aggs",
     "shuffled_segment_count",
+    "shuffle_mode",
 ]
+
+# below this many padded rows the all-to-all shuffle's ndev-fold padded
+# receive costs more than the cross-device combine it removes
+_SHUFFLE_MIN_ROWS = 1 << 15
+
+
+def shuffle_mode(conf_value: Any, conf_key: str) -> str:
+    """Normalize a shuffle conf value to ``auto`` / ``on`` / ``off``.
+    Shared by ``fugue.jax.shuffle`` and ``fugue.jax.shuffle.overlap``;
+    a misspelled opt-out must not silently keep shuffling."""
+    v = conf_value
+    if isinstance(v, bool):
+        return "on" if v else "off"
+    if v is None:
+        return "auto"
+    s = str(v).strip().lower()
+    if s in ("true", "1", "always", "on"):
+        return "on"
+    if s in ("false", "0", "never", "off"):
+        return "off"
+    if s == "auto":
+        return "auto"
+    raise ValueError(
+        f"{conf_key}={conf_value!r} is not one of auto/on/off"
+    )
+
+
+def choose_shuffle(
+    mode: str, mesh: Any, rows: int, num_segments: int
+) -> bool:
+    """The devices-aware strategy column: should this segment reduction
+    repartition rows by key (the all-to-all shuffle) so each device
+    reduces only its own segments?
+
+    Single-device meshes never shuffle (there is nothing to co-locate).
+    ``on`` forces it on any multi-device mesh; ``auto`` additionally
+    requires the frame to be large enough to amortize the padded
+    receive and enough segments that every device owns some."""
+    ndev = int(mesh.devices.size)
+    if mode == "off" or ndev <= 1 or num_segments < 1:
+        return False
+    if mode == "on":
+        return True
+    return rows >= _SHUFFLE_MIN_ROWS and num_segments >= 2 * ndev
+
+
+def choose_overlap(mode: str, mesh: Any, num_segments: int) -> bool:
+    """Collective/compute overlap: double-buffer the next key-range's
+    all-to-all behind the current range's local reduction. Worth it
+    only where collectives are asynchronous (accelerator meshes — CPU
+    runs them inline, so the second pass is pure overhead) and when the
+    segment space splits into two non-trivial ranges."""
+    ndev = int(mesh.devices.size)
+    if mode == "off" or ndev <= 1 or num_segments < 2 * ndev:
+        return False
+    if mode == "on":
+        return True
+    return mesh.devices.flat[0].platform != "cpu"
 
 
 def sharded_cumsum(mesh: Optional[Mesh], x: Any) -> Any:

@@ -101,7 +101,7 @@ def resolve_cache_dir(conf: Any, log: Any = None) -> str:
 
 def place_jax_compile_cache(checkout: str) -> str:
     """Turn on JAX's own persistent compilation cache for an ENTRY-POINT
-    script (``chip_smoke.py``, ``bench.py``) and return its directory.
+    script (``chip_smoke.py``) and return its directory.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing is
     set here. Unset: ``<checkout>/.jax_cache`` — a FIXED path, because

@@ -30,8 +30,8 @@ def test_defaults_table_matches_registry():
         if info.in_defaults:
             assert key in FUGUE_GLOBAL_CONF
             assert FUGUE_GLOBAL_CONF[key] == info.default
-        # defaults must satisfy their own declared type (object = any)
-        if info.type is not object and info.in_defaults:
+        # defaults must satisfy their own declared type
+        if info.in_defaults:
             assert isinstance(info.default, info.type) or (
                 info.type is float and isinstance(info.default, int)
             ), key
@@ -89,17 +89,39 @@ def test_typed_getters():
                        "fugue.workflow.retry.backoff")
         == 0.5
     )
-    # object-typed (mixed-type) keys pass through UNCOERCED
-    assert (
-        typed_conf_get({"fugue.jax.groupby.autotune": True},
-                       "fugue.jax.groupby.autotune")
-        is True
-    )
     with pytest.raises(ValueError):
         typed_conf_get({"fugue.workflow.retry.backoff": "soon"},
                        "fugue.workflow.retry.backoff")
     with pytest.raises(KeyError):
         conf_default("fugue.not.a.key")
+
+
+@pytest.mark.parametrize("knob", ["strategy", "autotune", "matmul"])
+def test_removed_groupby_keys_are_unknown(knob):
+    """The group-by kernel follows the shape alone: its three former keys
+    are undeclared, so the analyzer reports each as unknown (FWF201) and
+    an engine given one still aggregates."""
+    import pandas as pd
+
+    from fugue_tpu.collections.partition import PartitionSpec
+    from fugue_tpu.column import col
+    from fugue_tpu.column import functions as ff
+    from fugue_tpu.jax_backend import JaxExecutionEngine
+    from fugue_tpu.workflow.workflow import FugueWorkflow
+
+    key = "fugue.jax.groupby." + knob
+    assert key not in declared_conf_keys()
+    dag = FugueWorkflow()
+    dag.df([[0]], "a:int")
+    diags = dag.analyze(conf={key: "scatter"})
+    assert any(d.code == "FWF201" and key in d.message for d in diags)
+    e = JaxExecutionEngine({key: "scatter"})
+    out = e.aggregate(
+        e.to_df(pd.DataFrame({"k": [1, 1, 2], "v": [1.0, 2.0, 3.0]})),
+        PartitionSpec(by=["k"]), [ff.sum(col("v")).alias("s")],
+    ).as_pandas().sort_values("k")
+    assert out["s"].tolist() == [3.0, 3.0]
+    assert e.fallbacks == {}
 
 
 def test_plugin_keys_extend_the_live_registry():

@@ -58,6 +58,7 @@ def test_f64_groupby_two_float_keys():
 
 def test_matmul_chunk_bounded_at_segment_cap():
     from fugue_tpu.jax_backend import groupby
+    from fugue_tpu.jax_backend.groupby import matmul_segment_sums
 
     import jax.numpy as jnp
 
@@ -65,7 +66,7 @@ def test_matmul_chunk_bounded_at_segment_cap():
     num_segments = groupby._MATMUL_MAX_SEGMENTS
     seg = jnp.arange(n, dtype=jnp.int32) % num_segments
     vals = jnp.ones((n,), dtype=jnp.float32)
-    f_sums, c_sums = groupby.matmul_segment_sums(
+    f_sums, c_sums = matmul_segment_sums(
         [vals], [jnp.ones((n,), dtype=jnp.bool_)], seg, num_segments
     )
     assert float(f_sums[0].sum()) == n

@@ -1,9 +1,9 @@
-"""The adaptive segment-reduction strategy layer (ISSUE r6 tentpole):
-every strategy kernel must produce host-oracle-identical results on the
-execution-suite group-by shapes (masked columns, invalid rows with the
-out-of-range sentinel, DISTINCT aggregates, int payloads), the selector's
-tier/size routing is pinned per strategy, the autotune cache is one-shot,
-and the engine exposes per-strategy counters + XLA cost analysis."""
+"""The segment-reduction strategy layer: every strategy kernel produces
+host-oracle-identical results on the execution-suite group-by shapes
+(masked columns, invalid rows with the out-of-range sentinel, DISTINCT
+aggregates, int payloads), ``groupby.choose_strategy`` follows its
+measured table and eligibility, and the engine exposes per-strategy
+counters + XLA cost analysis."""
 
 from typing import Any
 
@@ -16,9 +16,9 @@ from fugue_tpu.column import col
 from fugue_tpu.column import functions as ff
 from fugue_tpu.column.expressions import function
 from fugue_tpu.execution.native_execution_engine import NativeExecutionEngine
-from fugue_tpu.jax_backend import JaxExecutionEngine, groupby, segtune
+from fugue_tpu.jax_backend import JaxExecutionEngine, groupby
 
-STRATS = ["matmul", "matmul_bf16", "scatter", "sort", "masked"]
+STRATS = ["matmul", "scatter", "sort", "masked"]
 
 
 def make_engine(**conf: Any) -> JaxExecutionEngine:
@@ -75,23 +75,24 @@ def _assert_matches(out: pd.DataFrame, oracle: pd.DataFrame, rtol: float):
 
 
 @pytest.mark.parametrize("strat", STRATS + ["auto"])
-def test_strategy_oracle_identity(strat):
-    """Each pinned strategy (and auto) matches the host oracle, including
-    DISTINCT aggregates, masked columns and null keys."""
+def test_strategy_oracle_identity(strat, force_strategy):
+    """Each forced strategy (and the rule's own pick) matches the host
+    oracle, including DISTINCT aggregates, masked columns and null keys."""
     pdf = _frame()
     oracle = _oracle_rows(pdf)
-    e = make_engine(**{"fugue.jax.groupby.strategy": strat})
+    if strat != "auto":
+        force_strategy(strat)
+    e = make_engine()
     out = e.aggregate(
         e.to_df(pdf), PartitionSpec(by=["k"]), list(_AGGS)
     ).as_pandas()
     assert e.fallbacks == {}, (strat, e.fallbacks)
-    # bf16 split keeps ~16 mantissa bits; everything else is f32/f64 exact
-    _assert_matches(out, oracle, rtol=2e-3 if strat == "matmul_bf16" else 1e-5)
+    _assert_matches(out, oracle, rtol=1e-5)
     assert sum(e.strategy_counts.values()) >= 1, e.strategy_counts
 
 
 @pytest.mark.parametrize("strat", STRATS)
-def test_strategy_oracle_identity_filtered_rows(strat):
+def test_strategy_oracle_identity_filtered_rows(strat, force_strategy):
     """Invalid rows (masked layout with the out-of-range sentinel) stay
     excluded on every strategy."""
     pdf = _frame()
@@ -101,7 +102,8 @@ def test_strategy_oracle_identity_filtered_rows(strat):
         native.to_df(filtered), PartitionSpec(by=["k"]),
         [ff.sum(col("v")).alias("s"), ff.count(col("k", "*")).alias("c")],
     ).as_pandas().sort_values("k", na_position="last").reset_index(drop=True)
-    e = make_engine(**{"fugue.jax.groupby.strategy": strat})
+    force_strategy(strat)
+    e = make_engine()
     jdf = e.filter(e.to_df(pdf), col("d") > 3.0)
     out = e.aggregate(
         jdf, PartitionSpec(by=["k"]),
@@ -109,17 +111,16 @@ def test_strategy_oracle_identity_filtered_rows(strat):
     ).as_pandas().sort_values("k", na_position="last").reset_index(drop=True)
     assert e.fallbacks == {}, (strat, e.fallbacks)
     assert out["c"].tolist() == oracle["c"].tolist()
-    rtol = 2e-3 if strat == "matmul_bf16" else 1e-5
     assert np.allclose(
         out["s"].astype(float), oracle["s"].astype(float),
-        rtol=rtol, atol=1e-3, equal_nan=True,
+        rtol=1e-5, atol=1e-3, equal_nan=True,
     )
     # pure float sum/count: every strategy is packed-path eligible
     assert e.strategy_counts.get(strat, 0) >= 1, (strat, e.strategy_counts)
 
 
 @pytest.mark.parametrize("strat", STRATS)
-def test_distinct_aggregates_ride_packed_path(strat):
+def test_distinct_aggregates_ride_packed_path(strat, force_strategy):
     """DISTINCT count/sum/avg fold their first-occurrence masks into the
     packed payloads and stay oracle-identical on every strategy (the
     native aggregate primitive has no DISTINCT — SQL is the oracle)."""
@@ -134,16 +135,16 @@ def test_distinct_aggregates_ride_packed_path(strat):
     exp = raw_sql(sql, pdf, "GROUP BY k", engine=native, as_fugue=True) \
         .as_pandas().sort_values("k", na_position="last") \
         .reset_index(drop=True)
-    e = make_engine(**{"fugue.jax.groupby.strategy": strat})
+    force_strategy(strat)
+    e = make_engine()
     got = raw_sql(sql, e.to_df(pdf), "GROUP BY k", engine=e, as_fugue=True) \
         .as_pandas().sort_values("k", na_position="last") \
         .reset_index(drop=True)
     assert got["cd"].tolist() == exp["cd"].tolist()
     assert got["sd"].fillna(-1).tolist() == exp["sd"].fillna(-1).tolist()
-    rtol = 2e-3 if strat == "matmul_bf16" else 1e-5
     assert np.allclose(
         got["ad"].astype(float), exp["ad"].astype(float),
-        rtol=rtol, atol=1e-3, equal_nan=True,
+        rtol=1e-5, atol=1e-3, equal_nan=True,
     )
     if strat in ("scatter", "sort"):
         # int DISTINCT sums are packed-eligible on the exact strategies
@@ -151,76 +152,46 @@ def test_distinct_aggregates_ride_packed_path(strat):
 
 
 def test_selector_tier_and_size_routing():
-    """The measured-table prior, pinned per strategy: CPU tier -> scatter;
-    accelerator below the one-hot cap -> matmul; above it -> sort; bf16
-    and explicit pins only through conf."""
-    assert segtune.heuristic_strategy("cpu", 1024, 3) == "scatter"
-    assert segtune.heuristic_strategy("cpu", 10**6, 3) == "scatter"
-    assert segtune.heuristic_strategy("tpu", 1024, 3) == "matmul"
-    assert segtune.heuristic_strategy(
-        "tpu", groupby._MATMUL_MAX_SEGMENTS, 2) == "matmul"
-    assert segtune.heuristic_strategy(
-        "tpu", groupby._MATMUL_MAX_SEGMENTS + 1, 2) == "sort"
-    assert segtune.heuristic_strategy("gpu", 100_000, 2) == "sort"
+    """``groupby.choose_strategy`` routes by tier, shape and eligibility:
+    CPU meshes scatter (counts too, at any segment count); accelerators
+    follow the measured table; exact-int payloads never get matmul or
+    masked; count reductions get matmul or scatter only; past every cap
+    the packed path gets no kernel."""
+    choose = groupby.choose_strategy
+    mm_cap = groupby._MATMUL_MAX_SEGMENTS
+    packed_cap = groupby._PACKED_MAX_SEGMENTS
+    for dt in (np.float32, np.float64):
+        assert choose("cpu", 10, dt) == "scatter"
+        assert choose("cpu", 10**6, dt) == "scatter"
+        assert choose("tpu", mm_cap + 1, dt) in ("scatter", "sort")
+    assert choose("cpu", 10, need_int=True) == "scatter"
+    assert choose("cpu", packed_cap + 1, count_only=True) == "scatter"
+    for platform in ("cpu", "tpu"):
+        assert choose(platform, packed_cap + 1) is None
+        assert choose(platform, packed_cap + 1, need_int=True) is None
+    for n in (12, 1024, mm_cap, mm_cap + 1, packed_cap):
+        assert choose("tpu", n, need_int=True) in ("scatter", "sort")
+        for dt in (np.float32, np.float64):
+            assert choose("tpu", n, dt, count_only=True) in (
+                "matmul", "scatter"
+            )
+    assert choose("tpu", mm_cap, count_only=True) == "matmul"
+    assert choose("tpu", mm_cap + 1, count_only=True) == "scatter"
+    # f64 keeps the emulated matmul while the segments fit one lane tile
+    tile = groupby._MATMUL_F64_MAX_SEGMENTS
+    assert choose("tpu", tile, np.float64) == "matmul"
+    assert choose("tpu", tile + 1, np.float64) == "scatter"
+    assert choose("tpu", packed_cap + 1, count_only=True) == "scatter"
+    # every accelerator follows the one table
+    assert choose("gpu", 1024, np.float64) == choose("tpu", 1024, np.float64)
 
+    # on the engine's CPU mesh the packed path runs scatter
     e = make_engine()
-    blocks = e.to_df(_frame(64)).blocks
-    # CPU mesh auto -> scatter for the packed path AND the count shape
-    assert e._groupby_strategy(blocks, 64, 10, 3) == "scatter"
-    assert e._count_reduce_strategy(blocks, 10) == "scatter"
-    # exact-int payloads exclude the matmul family even when pinned
-    pinned = make_engine(**{"fugue.jax.groupby.strategy": "matmul"})
-    assert pinned._groupby_strategy(blocks, 64, 10, 3, need_int=True) is None
-    assert pinned._groupby_strategy(blocks, 64, 10, 3) == "matmul"
-    # bf16 pin needs all-f32 payloads
-    b16 = make_engine(**{"fugue.jax.groupby.strategy": "matmul_bf16"})
-    assert b16._groupby_strategy(
-        blocks, 64, 10, 3, float_dtype=np.float64
-    ) is None
-    assert b16._groupby_strategy(blocks, 64, 10, 3) == "matmul_bf16"
-    # over every cap: no packed strategy at all
-    assert (
-        pinned._groupby_strategy(
-            blocks, 64, groupby._PACKED_MAX_SEGMENTS + 1, 3
-        )
-        is None
-    )
-    # legacy knob still maps onto the strategy layer
-    legacy = make_engine(**{"fugue.jax.groupby.matmul": "always"})
-    assert legacy._groupby_strategy(blocks, 64, 10, 3) == "matmul"
-    legacy2 = make_engine(**{"fugue.jax.groupby.matmul": "never"})
-    assert legacy2._groupby_strategy(blocks, 64, 10, 3) == "scatter"
-
-
-def test_autotune_cache_is_one_shot():
-    """The on-device autotune probes ONCE per shape bucket per process and
-    serves the cached winner afterwards."""
-    e = make_engine()
-    mesh = e.to_df(_frame(64)).blocks.mesh
-    segtune.clear_cache()
-    before = segtune._TUNE_RUNS["count"]
-    first = segtune.choose_strategy(
-        mesh, 1 << 16, 256, 3, ["matmul", "scatter", "sort"],
-        autotune_conf=True,
-    )
-    assert first in ("matmul", "scatter", "sort")
-    assert segtune._TUNE_RUNS["count"] == before + 1
-    again = segtune.choose_strategy(
-        mesh, 1 << 16, 256, 3, ["matmul", "scatter", "sort"],
-        autotune_conf=True,
-    )
-    assert again == first
-    assert segtune._TUNE_RUNS["count"] == before + 1  # cache hit, no probe
-    # "auto" never probes on CPU meshes (tier-1 must not pay compiles)
-    assert (
-        segtune.choose_strategy(
-            mesh, 1 << 30, 256, 3, ["matmul", "scatter"],
-            autotune_conf="auto",
-        )
-        == "scatter"
-    )
-    assert segtune._TUNE_RUNS["count"] == before + 1
-    segtune.clear_cache()
+    e.aggregate(
+        e.to_df(_frame(64)), PartitionSpec(by=["k"]),
+        [ff.sum(col("v")).alias("s"), ff.count(col("v")).alias("c")],
+    ).as_pandas()
+    assert e.strategy_counts == {"scatter": 1}, e.strategy_counts
 
 
 def _q1_frame(n: int = 5000) -> pd.DataFrame:
@@ -239,13 +210,14 @@ def _q1_frame(n: int = 5000) -> pd.DataFrame:
     return pdf
 
 
-def test_masked_q1_shape_matches_numpy_f64():
+def test_masked_q1_shape_matches_numpy_f64(force_strategy):
     """A TPC-H Q1-shaped group-by on the masked kernel: two string keys
     (one with nulls), f64 sums and averages and COUNT(*), after a filter
     whose dropped rows carry the out-of-range sentinel — checked against
     numpy float64."""
     pdf = _q1_frame()
-    e = make_engine(**{"fugue.jax.groupby.strategy": "masked"})
+    force_strategy("masked")
+    e = make_engine()
     jdf = e.filter(e.to_df(pdf), col("ship") <= 80)
     out = e.aggregate(
         jdf, PartitionSpec(by=["flag", "status"]),
@@ -295,70 +267,59 @@ def test_masked_q1_shape_matches_numpy_f64():
 
 
 def test_selector_masked_for_f64_only():
-    """masked is the accelerator prior for f64 payloads over few bins; f32
-    keeps the MXU matmul, CPU meshes keep scatter, and past the cap f64
-    takes another kernel. On the engine, auto offers masked for f64 only,
-    and a pin takes it for any float payloads."""
+    """masked is the accelerator's kernel for f64 payloads over few bins;
+    f32 keeps another kernel, CPU meshes keep scatter, and past the cap
+    f64 takes another kernel. masked stays eligible for any float
+    payloads (a test may force it), never for exact ints, count
+    reductions or past its cap."""
     cap = groupby._MASKED_MAX_SEGMENTS
-    assert segtune.heuristic_strategy("tpu", 6, 9, np.float64) == "masked"
-    assert segtune.heuristic_strategy("tpu", cap, 9, np.float64) == "masked"
-    assert segtune.heuristic_strategy("tpu", 6, 9, np.float32) == "matmul"
-    assert segtune.heuristic_strategy("tpu", 6, 9) == "matmul"
-    assert segtune.heuristic_strategy("cpu", 6, 9, np.float64) == "scatter"
-    assert segtune.heuristic_strategy(
-        "tpu", cap + 1, 9, np.float64
-    ) != "masked"
-
-    e = make_engine()
-    blocks = e.to_df(_frame(64)).blocks
-    # CPU mesh auto: scatter whatever the float dtype
-    assert e._groupby_strategy(
-        blocks, 64, 6, 9, float_dtype=np.float64
-    ) == "scatter"
-    pinned = make_engine(**{"fugue.jax.groupby.strategy": "masked"})
-    assert pinned._groupby_strategy(
-        blocks, 64, 6, 9, float_dtype=np.float64
-    ) == "masked"
-    assert pinned._groupby_strategy(blocks, 64, 6, 9) == "masked"
-    # exact-int payloads and more bins than the cap exclude it
-    assert pinned._groupby_strategy(blocks, 64, 6, 9, need_int=True) is None
-    assert pinned._groupby_strategy(
-        blocks, 64, cap + 1, 9, float_dtype=np.float64
-    ) is None
+    choose = groupby.choose_strategy
+    assert choose("tpu", 6, np.float64) == "masked"
+    assert choose("tpu", cap, np.float64) == "masked"
+    assert choose("tpu", 6, np.float32) != "masked"
+    assert choose("tpu", 6) == choose("tpu", 6, np.float32)
+    assert choose("cpu", 6, np.float64) == "scatter"
+    assert choose("tpu", cap + 1, np.float64) != "masked"
+    assert choose("tpu", 6, np.float64, count_only=True) != "masked"
+    assert "masked" in groupby.eligible_strategies(6)
+    assert "masked" not in groupby.eligible_strategies(6, need_int=True)
+    assert "masked" not in groupby.eligible_strategies(6, count_only=True)
+    assert "masked" not in groupby.eligible_strategies(cap + 1)
 
 
-def test_autotune_key_has_float_dtype():
-    """One shape probed in f32 and in f64 gives two probes and two cache
-    entries; repeating either is a cache hit."""
-    e = make_engine()
-    mesh = e.to_df(_frame(64)).blocks.mesh
-    cands = ["matmul", "masked", "scatter", "sort"]
-    segtune.clear_cache()
-    before = segtune._TUNE_RUNS["count"]
-    try:
-        picks = {}
-        for dt in (np.float32, np.float64):
-            picks[dt] = segtune.choose_strategy(
-                mesh, 1 << 15, 6, 6, cands, autotune_conf=True,
-                float_dtype=dt,
-            )
-            assert picks[dt] in cands
-        assert segtune._TUNE_RUNS["count"] == before + 2
-        assert len(segtune._TUNE_CACHE) == 2
-        for dt in (np.float32, np.float64):
-            assert segtune.choose_strategy(
-                mesh, 1 << 15, 6, 6, cands, autotune_conf=True,
-                float_dtype=dt,
-            ) == picks[dt]
-        assert segtune._TUNE_RUNS["count"] == before + 2
-    finally:
-        segtune.clear_cache()
+# The v5e table (PERF.md §6, and groupby.py's strategy comment): one chip,
+# 1M rows, 8 payloads of the dtype and a count; "int" is the exact-integer
+# case. The kernel is the one that measured fastest.
+_V5E_TABLE = [
+    ("float32", 12, "matmul"),
+    ("float32", 64, "matmul"),
+    ("float32", 65, "matmul"),
+    ("float32", 1024, "matmul"),
+    ("float32", 8192, "matmul"),
+    ("float32", 8193, "scatter"),
+    ("float64", 12, "masked"),
+    ("float64", 64, "masked"),
+    ("float64", 65, "matmul"),
+    ("float64", 1024, "scatter"),
+    ("float64", 8192, "scatter"),
+    ("float64", 8193, "scatter"),
+    ("int", 12, "scatter"),
+    ("int", 1024, "scatter"),
+    ("int", 8193, "scatter"),
+]
+
+
+@pytest.mark.parametrize("payloads,segments,kernel", _V5E_TABLE)
+def test_choose_strategy_table(payloads, segments, kernel):
+    need_int = payloads == "int"
+    dtype = np.float64 if need_int else np.dtype(payloads)
+    assert groupby.choose_strategy("tpu", segments, dtype, need_int) == kernel
 
 
 @pytest.mark.parametrize("strat", ["matmul", "scatter", "sort"])
-def test_join_side_counts_follow_strategy(strat):
+def test_join_side_counts_follow_strategy(strat, force_strategy):
     """Join-side count reductions share the strategy layer: results are
-    identical to the host under every pinned strategy."""
+    identical to the host under every forced strategy."""
     rng = np.random.default_rng(3)
     left = pd.DataFrame(
         {
@@ -370,7 +331,8 @@ def test_join_side_counts_follow_strategy(strat):
         {"k": np.arange(8, dtype=np.int64), "w": rng.random(8)}
     )
     native = NativeExecutionEngine(dict(test=True))
-    e = make_engine(**{"fugue.jax.groupby.strategy": strat})
+    force_strategy(strat)
+    e = make_engine()
     for how in ("inner", "semi", "left_anti", "left_outer"):
         exp = native.join(
             native.to_df(left), native.to_df(right), how=how
@@ -381,11 +343,12 @@ def test_join_side_counts_follow_strategy(strat):
         pd.testing.assert_frame_equal(got, exp, check_dtype=False)
 
 
-def test_program_cost_analysis_reports_traffic():
+def test_program_cost_analysis_reports_traffic(force_strategy):
     """The engine can AOT-lower the programs it just ran and read XLA's
     own flops/bytes accounting (the roofline's % of peak denominator)."""
     pdf = _frame(2000)
-    e = make_engine(**{"fugue.jax.groupby.strategy": "scatter"})
+    force_strategy("scatter")
+    e = make_engine()
     jdf = e.to_df(pdf)
     e.reset_program_log()
     e.aggregate(

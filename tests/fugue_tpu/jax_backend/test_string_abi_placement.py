@@ -211,16 +211,16 @@ def test_cross_mesh_join_aligns():
     assert out["w"].sum() == expect["w"].sum()
 
 
-def test_groupby_matmul_conf_paths_agree():
-    """fugue.jax.groupby.matmul: 'always' (the accelerator path) and
-    'never' (the CPU scatter path) must agree bit-for-bit on counts and
-    to rounding on sums; 'auto' picks scatter on CPU meshes."""
+def test_groupby_matmul_and_scatter_paths_agree(force_strategy):
+    """The matmul kernel (the accelerator path) and the scatter kernel
+    (the CPU path) agree bit-for-bit on counts and to rounding on sums;
+    the rule picks scatter on CPU meshes."""
     import pandas as pd
 
     from fugue_tpu.collections.partition import PartitionSpec
     from fugue_tpu.column import col as fcol
     from fugue_tpu.column import functions as ff
-    from fugue_tpu.constants import FUGUE_CONF_JAX_GROUPBY_MATMUL
+    from fugue_tpu.jax_backend import groupby
 
     rng = np.random.default_rng(5)
     pdf = pd.DataFrame(
@@ -229,24 +229,25 @@ def test_groupby_matmul_conf_paths_agree():
             "v": rng.random(5000).astype(np.float32),
         }
     )
+    # the rule on a CPU mesh: scatter for the packed path and the counts
+    platform = JaxExecutionEngine().mesh.devices.flat[0].platform
+    assert groupby.choose_strategy(platform, 16, np.float32) == "scatter"
+    assert groupby.choose_strategy(platform, 16, count_only=True) == "scatter"
     results = {}
-    for mode in ("always", "never"):
-        e = JaxExecutionEngine({FUGUE_CONF_JAX_GROUPBY_MATMUL: mode})
+    for strategy in ("matmul", "scatter"):
+        force_strategy(strategy)
+        e = JaxExecutionEngine()
         out = e.aggregate(
             e.to_df(pdf), PartitionSpec(by=["k"]),
             [ff.sum(fcol("v")).alias("s"), ff.count(fcol("k")).alias("c")],
         ).as_pandas().sort_values("k").reset_index(drop=True)
-        assert e.fallbacks == {}, (mode, e.fallbacks)
-        results[mode] = out
-    a, b = results["always"], results["never"]
+        assert e.fallbacks == {}, (strategy, e.fallbacks)
+        assert e.strategy_counts == {strategy: 1}, e.strategy_counts
+        results[strategy] = out
+    a, b = results["matmul"], results["scatter"]
     assert a["k"].tolist() == b["k"].tolist()
     assert a["c"].tolist() == b["c"].tolist()
     assert np.allclose(a["s"], b["s"], rtol=1e-5)
-    # auto on a CPU mesh = the scatter strategy
-    e = JaxExecutionEngine()
-    blocks = e.to_df(pdf).blocks
-    assert e._groupby_strategy(blocks, 5000, 16, 3) == "scatter"
-    assert e._count_reduce_strategy(blocks, 16) == "scatter"
 
 
 def test_compile_cache_conf(monkeypatch):

@@ -188,19 +188,19 @@ def test_q1_builds_no_python_dictionary_of_an_unread_column(strict_dictionaries)
 
 
 @pytest.mark.parametrize("ndev", [1, 4])
-def test_q1_masked_on_the_mesh_matches_the_reference(ndev):
+def test_q1_masked_on_the_mesh_matches_the_reference(ndev, force_strategy):
     from benchmark.compare import compare
     from benchmark.queries import q1
     from benchmark.reference import q1 as ref
 
     table = _lineitem(48_000, 200_000)
     devices = ",".join(str(i) for i in range(ndev))
+    force_strategy("masked")
     engine = make_execution_engine(
         "jax",
         {
             "fugue.jax.placement": "device",
             "fugue.jax.devices": devices,
-            "fugue.jax.groupby.strategy": "masked",
         },
     )
     for delta in (60, 120):

@@ -71,9 +71,9 @@ def _compile(fn, *args):
 
 @pytest.mark.parametrize("strategy", groupby.STRATEGIES)
 def test_segment_sums_compiles(one_chip, strategy):
-    # matmul_bf16 takes f32 payloads only; the others keep the engine's f64
+    # every kernel in the engine's f64
     n = SORT_ROWS if strategy == "sort" else ROWS
-    dtype = jnp.float32 if strategy == "matmul_bf16" else jnp.float64
+    dtype = jnp.float64
 
     def prog(v, seg, valid):
         f, c, _ = groupby.segment_sums([v], [valid], seg, SEGMENTS, strategy)

@@ -76,7 +76,7 @@ def test_different_jax_conf_never_shares_a_slot():
 
     e1 = make_execution_engine("jax", {})
     e2 = make_execution_engine(
-        "jax", {"fugue.jax.groupby.strategy": "scatter"}
+        "jax", {"fugue.jax.shuffle": "off"}
     )
     assert engine_plan_signature(e1) != engine_plan_signature(e2)
 
